@@ -852,6 +852,8 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
     SMOKE = args.smoke
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     rows: list = []
     print("benchmark,metric,value")

@@ -61,6 +61,7 @@ from dataclasses import replace
 
 from repro.configs.base import FedHPConfig
 from repro.core.experiment import churn_from_config, run_algorithm
+from repro.launch.cache import enable_compile_cache
 
 CFG = FedHPConfig(num_workers=10, rounds=100, tau_init=8, tau_max=30,
                   lr=0.15, lr_decay=0.993, batch_size=32, seed=7)
@@ -261,6 +262,7 @@ def main():
     ap.add_argument("--fused", action="store_true",
                     help="run the algorithms on the fused scan engines")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.churn:
         churn_study(fused=args.fused)
     elif args.scenarios:

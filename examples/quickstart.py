@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from repro.configs import SHAPES, get_smoke_config
 from repro.configs.base import FedHPConfig
 from repro.core.experiment import run_algorithm
+from repro.launch.cache import enable_compile_cache
 from repro.models import registry
 
 
@@ -49,6 +50,11 @@ def model_demo():
     print(f"  arch={cfg.name} params={n:,} loss={float(loss):.3f}")
 
 
-if __name__ == "__main__":
+def main():
+    enable_compile_cache()
     dfl_demo()
     model_demo()
+
+
+if __name__ == "__main__":
+    main()

@@ -7,6 +7,8 @@ import os
 import subprocess
 import sys
 
+from repro.launch.cache import enable_compile_cache
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -14,6 +16,8 @@ def main():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     env["REPRO_DEVICES"] = "4"
+    # the child processes share this run's compile cache directory
+    env["JAX_COMPILATION_CACHE_DIR"] = enable_compile_cache()
     for arch in ("smollm-360m", "olmoe-1b-7b", "zamba2-7b"):
         cmd = [sys.executable, "-m", "repro.launch.serve", "--arch", arch,
                "--smoke", "--batch", "2", "--prompt-len", "16",

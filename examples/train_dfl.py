@@ -15,6 +15,8 @@ import subprocess
 import sys
 import tempfile
 
+from repro.launch.cache import enable_compile_cache
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -28,6 +30,8 @@ def main():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     env["REPRO_DEVICES"] = "8"
+    # the child processes share this run's compile cache directory
+    env["JAX_COMPILATION_CACHE_DIR"] = enable_compile_cache()
     with tempfile.TemporaryDirectory() as ckdir:
         # leg 1: 6 rounds with checkpoints every 3
         run(["--arch", "smollm-360m", "--smoke", "--steps", "6",

@@ -28,3 +28,12 @@ def smoke_config() -> ModelConfig:
     return dataclasses.replace(
         CONFIG, num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
         d_ff=128, vocab_size=256, dtype="float32")
+
+
+def dfl_model_spec(*, layers: int, vocab: int, seq: int) -> str:
+    """``FedHPConfig.model`` spec of one DFL worker at this config's
+    published widths (d_model, heads, KV heads, d_ff), cut only in depth,
+    vocabulary and sequence length (``core/modelspec.get_adapter``)."""
+    c = CONFIG
+    return (f"dense:d={c.d_model},heads={c.num_heads},kv={c.num_kv_heads},"
+            f"ff={c.d_ff},layers={layers},vocab={vocab},seq={seq}")

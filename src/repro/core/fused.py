@@ -14,9 +14,10 @@ and ``run_adpsgd``.
   ``replan_every=1`` the fused engine replans every round exactly like
   the reference; larger segments freeze (A^h, tau^h) within a segment —
   a documented behavioral deviation bought for throughput (README.md).
-- Gossip (Eq. 5-6) runs through the Pallas ``gossip_mix_2d`` kernel on
-  the flattened [W, P] parameter matrix; the kernel's padding shim means
-  P need not be a tile multiple, so real model sizes work.
+- Gossip (Eq. 5-6) runs through the Pallas ``gossip_mix_rows`` kernel
+  on the flattened [W, P] parameter matrix as it lies (column tiles, all
+  worker rows resident), so P need not be a tile multiple and no
+  relayout feeds the kernel.
 - ``cfg.gossip == "sparse"`` swaps the dense [W, W] mixing for the
   edge-list path: per-round directed edge arrays (padded to a static
   E_max with zero-weight no-op edges) ride the scan instead of [K, W, W]
@@ -76,7 +77,7 @@ from repro.core.engine import (AdpsgdSchedule, History, RoundRecord,
                                _unflatten, _unflatten_row, adpsgd_schedule)
 from repro.data.synthetic import Dataset
 from repro.kernels.gossip_edges import gossip_edges
-from repro.kernels.gossip_mix import gossip_mix_2d
+from repro.kernels.gossip_mix import gossip_mix_2d, gossip_mix_rows
 from repro.kernels.robust_gossip import robust_gossip
 from repro.runtime.collectives import (_shard_map, edge_shard_tables,
                                        routed_mix_delta)
@@ -153,9 +154,6 @@ def _scan_segment(stacked, err, bx, by, ex, ey, px, py, taus, lrs, mixes,
     # rand-k carries nothing; mirrors compression.carries_state so the
     # scan state matches the reference engine bit for bit
     stateful = compress and compression.carries_state(kind, ef)
-    leaves = jax.tree.leaves(stacked)
-    p_total = sum(int(np.prod(l.shape[2:])) for l in leaves)
-    rows, cols = compression.flat_tile_shape(p_total)
 
     def one_seed(stacked, err, bx, by, ex, ey, px, py):
 
@@ -275,19 +273,12 @@ def _scan_segment(stacked, err, bx, by, ex, ey, px, py, taus, lrs, mixes,
                 y_flat = gossip_edges(flat, src_h, dst_h, wgt_h,
                                       interpret=interpret)
             else:
-                # --- gossip (Eq. 5-6) through the Pallas kernel on
-                # [W, R, C]. Row i of the mixing matrix becomes the
-                # kernel's neighbor weights: y_i = x_i + sum_j w_ij
-                # (x_j - x_i) = sum_j w_ij x_j for a row-stochastic mix;
-                # rounds without communication carry an identity mix,
-                # which the kernel maps to an exact no-op ---
-                x2 = jnp.pad(flat, ((0, 0), (0, rows * cols - p_total)))
-                x2 = x2.reshape(-1, rows, cols)
-                y2 = jax.vmap(
-                    lambda xi, wi: gossip_mix_2d(xi, x2, wi,
-                                                 interpret=interpret))(
-                    x2, mix_h)
-                y_flat = y2.reshape(y2.shape[0], -1)[:, :p_total]
+                # --- gossip (Eq. 5-6) through the Pallas kernel on the
+                # [W, P] rows: y_i = x_i + sum_j w_ij (x_j - x_i) =
+                # sum_j w_ij x_j for a row-stochastic mix; rounds without
+                # communication carry an identity mix, which the kernel
+                # maps to an exact no-op ---
+                y_flat = gossip_mix_rows(flat, mix_h, interpret=interpret)
             carry = _unflatten(y_flat, carry)
 
             # --- per-round metrics: fleet accuracy/loss over alive

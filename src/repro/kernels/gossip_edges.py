@@ -15,7 +15,9 @@ rows of its tile resident and walks the whole edge list with a
 a multiple of 8 (sublane), edges to a multiple of 8 with zero-weight
 self-loops at vertex 0, which contribute ``0 * (x_0 - x_0) = 0``
 exactly — this is what lets callers pad per-round edge arrays to a
-static E_max inside ``lax.scan``.
+static E_max inside ``lax.scan``. The edge tables are whole-array SMEM
+operands: the walk reads one scalar per edge at a dynamic index, which
+TPU scalar memory serves and a VMEM lane load cannot.
 """
 from __future__ import annotations
 
@@ -24,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_COLS = 256        # all W rows stay resident per program: keep tiles lean
 _EDGE_PAD = 8
@@ -33,9 +36,9 @@ def _edges_kernel(src_ref, dst_ref, w_ref, x_ref, o_ref, *, num_edges: int):
     o_ref[...] = x_ref[...]
 
     def body(e, carry):
-        s = src_ref[0, e]
-        d = dst_ref[0, e]
-        we = w_ref[0, e]
+        s = src_ref[e]
+        d = dst_ref[e]
+        we = w_ref[e]
         xs = x_ref[pl.ds(s, 1), :].astype(jnp.float32)
         xd = x_ref[pl.ds(d, 1), :].astype(jnp.float32)
         cur = o_ref[pl.ds(d, 1), :].astype(jnp.float32)
@@ -78,20 +81,16 @@ def gossip_edges(x, src, dst, w, *, interpret: bool = False):
     if (rp, cp) != (r, c):
         x = jnp.pad(x, ((0, rp - r), (0, cp - c)))
     kernel = functools.partial(_edges_kernel, num_edges=ep)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         kernel,
         grid=(cp // bc,),
-        in_specs=[
-            pl.BlockSpec((1, ep), lambda j: (0, 0)),
-            pl.BlockSpec((1, ep), lambda j: (0, 0)),
-            pl.BlockSpec((1, ep), lambda j: (0, 0)),
-            pl.BlockSpec((rp, bc), lambda j: (0, j)),
-        ],
+        in_specs=[smem, smem, smem,
+                  pl.BlockSpec((rp, bc), lambda j: (0, j))],
         out_specs=pl.BlockSpec((rp, bc), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((rp, cp), x.dtype),
         interpret=interpret,
-    )(src.reshape(1, ep), dst.reshape(1, ep),
-      w.reshape(1, ep).astype(jnp.float32), x)
+    )(src, dst, w, x)
     if (rp, cp) != (r, c):
         out = out[:r, :c]
     return out

@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 
 BLOCK_ROWS = 8
 BLOCK_COLS = 1024
+ROWS_BLOCK_COLS = 512   # gossip_mix_rows: all W rows of a column tile resident
 
 
 def _gossip_kernel(w_ref, x_ref, u_ref, o_ref, *, num_neighbors: int):
@@ -70,3 +71,37 @@ def gossip_mix_2d(x, u, w, *, interpret: bool = False):
     if (rp, cp) != (r, c):
         out = out[:r, :c]
     return out
+
+
+def _rows_kernel(m_ref, x_ref, o_ref, *, num_workers: int):
+    x = x_ref[...].astype(jnp.float32)                    # [W, bc]
+    acc = x
+    for k in range(num_workers):                          # W is small/static
+        acc = acc + m_ref[:, k:k + 1] * (x[k:k + 1, :] - x)
+    o_ref[...] = acc.astype(o_ref.dtype)
+
+
+def gossip_mix_rows(x, mix, *, interpret: bool = False):
+    """x: [W, C] worker rows; mix: [W, W] mixing matrix.
+
+    Returns y with ``y_i = x_i + sum_k mix[i, k] (x_k - x_i)``, summed
+    over k in ascending order: per element the arithmetic of
+    ``gossip_mix_2d`` vmapped over the rows of ``mix``, but on the [W, C]
+    row layout the engines hold, so no [W, rows, 1024] relayout (and no
+    padded copy) feeds it. One program per column tile with every worker
+    row resident (the ``gossip_edges`` layout); a ragged last tile is
+    masked by Pallas, and columns never mix with each other."""
+    r, c = x.shape
+    bc = min(ROWS_BLOCK_COLS, c)
+    kernel = functools.partial(_rows_kernel, num_workers=r)
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(c, bc),),
+        in_specs=[
+            pl.BlockSpec((r, r), lambda j: (0, 0)),          # mix: whole
+            pl.BlockSpec((r, bc), lambda j: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((r, bc), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((r, c), x.dtype),
+        interpret=interpret,
+    )(mix.astype(jnp.float32), x)

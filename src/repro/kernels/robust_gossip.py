@@ -10,17 +10,21 @@ therefore scannable inside the fused round loop.
 
 Grid: one program per column tile (the ``gossip_edges`` layout — all
 padded W rows of the tile stay resident). Each program walks the
-workers with a ``fori_loop``; per worker it gathers the own row plus up
-to D transmitted rows via dynamic row slices (``pl.ds``) into a
-``[D + 1, BC]`` window, masks padding slots (index >= deg) to +inf,
-sorts the window rows with an odd-even transposition network (D + 1
-static compare-exchange passes of elementwise min/max — no
-data-dependent control flow, so it lowers the same everywhere), and
-reduces the sorted window:
+workers with a ``fori_loop``; per worker it gathers the own row plus
+D transmitted rows via dynamic row slices (``pl.ds``) into a window of
+``D + 1`` separate ``[1, BC]`` rows (D is static, so the gather is
+unrolled and no window buffer is updated in place), masks padding slots
+(index >= deg) to +inf, sorts the window with an odd-even transposition
+network (D + 1 static passes of elementwise min/max compare-exchanges
+— no data-dependent control flow, so it lowers the same everywhere),
+and reduces the sorted window:
 
 - ``trimmed``: average of positions ``[b_i, cnt - b_i)`` where
   ``cnt = deg + 1`` and ``b_i`` is the per-worker clamped trim count;
 - ``median``: mean of the two middle order statistics.
+
+The neighbour and degree tables are whole-array SMEM operands: the walk
+reads them one scalar at a time at dynamic indices.
 
 Workers with no neighbors (including padded rows) keep their row
 exactly, so row padding is a no-op like the zero-weight padding edges
@@ -33,46 +37,37 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_COLS = 256        # all W rows stay resident per program: keep tiles lean
 
 
-def _sort_rows(win):
-    """Odd-even transposition sort of the window rows (ascending), one
-    independent network per column. n static passes of vectorized
-    compare-exchange — +inf padding rows sink to the bottom."""
-    n = win.shape[0]
-    idx = jnp.arange(n)[:, None]
+def _sort_rows(rows):
+    """Odd-even transposition sort of a list of [1, BC] rows (ascending,
+    one independent network per column): ``len(rows)`` static passes of
+    elementwise compare-exchanges — +inf padding rows sink to the end."""
+    rows = list(rows)
+    n = len(rows)
     for p in range(n):
-        q = p % 2
-        up = jnp.roll(win, -1, axis=0)      # row r sees row r+1's value
-        down = jnp.roll(win, 1, axis=0)     # row r sees row r-1's value
-        is_lo = ((idx - q) % 2 == 0) & (idx + 1 < n)
-        is_hi = ((idx - q) % 2 == 1) & (idx >= 1)
-        win = jnp.where(is_lo, jnp.minimum(win, up),
-                        jnp.where(is_hi, jnp.maximum(win, down), win))
-    return win
+        for r in range(p % 2, n - 1, 2):
+            lo, hi = rows[r], rows[r + 1]
+            rows[r], rows[r + 1] = jnp.minimum(lo, hi), jnp.maximum(lo, hi)
+    return rows
 
 
 def _robust_kernel(nbr_ref, deg_ref, x_ref, t_ref, o_ref, *,
                    num_workers: int, d_pad: int, b: float, mode: str):
     """Per-column-tile program: gather-sort-trim every worker's window."""
-    bc = x_ref.shape[1]
     inf = jnp.float32(jnp.inf)
 
     def worker(i, carry):
-        d = deg_ref[0, i]
+        d = deg_ref[i]
         own = x_ref[pl.ds(i, 1), :].astype(jnp.float32)          # [1, bc]
-        win = jnp.full((d_pad + 1, bc), inf, jnp.float32)
-        win = jax.lax.dynamic_update_slice(win, own, (0, 0))
-
-        def gather(k, win):
-            j = nbr_ref[0, i * d_pad + k]
+        win = [own]
+        for k in range(d_pad):
+            j = nbr_ref[i * d_pad + k]
             row = t_ref[pl.ds(j, 1), :].astype(jnp.float32)
-            row = jnp.where(k < d, row, inf)
-            return jax.lax.dynamic_update_slice(win, row, (k + 1, 0))
-
-        win = jax.lax.fori_loop(0, d_pad, gather, win)
+            win.append(jnp.where(k < d, row, inf))
         win = _sort_rows(win)
         cnt = d + 1
         if mode == "trimmed":
@@ -81,15 +76,18 @@ def _robust_kernel(nbr_ref, deg_ref, x_ref, t_ref, o_ref, *,
             else:
                 bi = jnp.int32(int(b))
             bi = jnp.minimum(bi, (cnt - 1) // 2)
-            pos = jnp.arange(d_pad + 1)[:, None]
-            inside = (pos >= bi) & (pos < cnt - bi)
-            y = jnp.where(inside & jnp.isfinite(win), win, 0.0)
-            y = y.sum(axis=0, keepdims=True) / (cnt - 2 * bi)
+            y = jnp.zeros_like(own)
+            for pos, row in enumerate(win):
+                inside = (pos >= bi) & (pos < cnt - bi)
+                y = y + jnp.where(inside & jnp.isfinite(row), row, 0.0)
+            y = y / (cnt - 2 * bi)
         else:                                                    # median
             lo = (cnt - 1) // 2
             hi = cnt // 2
-            vlo = jax.lax.dynamic_slice(win, (lo, 0), (1, bc))
-            vhi = jax.lax.dynamic_slice(win, (hi, 0), (1, bc))
+            vlo = vhi = win[0]
+            for pos, row in enumerate(win[1:], 1):
+                vlo = jnp.where(pos == lo, row, vlo)
+                vhi = jnp.where(pos == hi, row, vhi)
             y = 0.5 * (vlo + vhi)
         y = jnp.where(d > 0, y, own)
         o_ref[pl.ds(i, 1), :] = y.astype(o_ref.dtype)
@@ -124,19 +122,17 @@ def robust_gossip(x, t, nbr, deg, *, b: float, mode: str,
         deg = jnp.pad(deg, (0, rp - r))
     kernel = functools.partial(_robust_kernel, num_workers=rp,
                                d_pad=d_pad, b=b, mode=mode)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         kernel,
         grid=(cp // bc,),
-        in_specs=[
-            pl.BlockSpec((1, rp * d_pad), lambda j: (0, 0)),
-            pl.BlockSpec((1, rp), lambda j: (0, 0)),
-            pl.BlockSpec((rp, bc), lambda j: (0, j)),
-            pl.BlockSpec((rp, bc), lambda j: (0, j)),
-        ],
+        in_specs=[smem, smem,
+                  pl.BlockSpec((rp, bc), lambda j: (0, j)),
+                  pl.BlockSpec((rp, bc), lambda j: (0, j))],
         out_specs=pl.BlockSpec((rp, bc), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((rp, cp), jnp.float32),
         interpret=interpret,
-    )(nbr.reshape(1, rp * d_pad), deg.reshape(1, rp), x, t)
+    )(nbr.reshape(rp * d_pad), deg, x, t)
     if (rp, cp) != (r, c):
         out = out[:r, :c]
     return out

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.gossip_mix import pad_to_blocks
+from repro.kernels.quantize_block import LANES, lane_row_spec
 
 BLOCK_ROWS = 8
 BLOCK_COLS = 1024
@@ -32,7 +33,8 @@ def _sparsify_kernel(t_ref, x_ref, g_ref, y_ref, n_ref):
     x = x_ref[...]
     keep = g_ref[...].astype(jnp.float32) >= t_ref[0, 0]
     y_ref[...] = jnp.where(keep, x, jnp.zeros_like(x)).astype(y_ref.dtype)
-    n_ref[0, 0] = jnp.sum(keep.astype(jnp.int32))
+    n_ref[...] = jnp.full(n_ref.shape, jnp.sum(keep.astype(jnp.int32)),
+                          jnp.int32)
 
 
 def sparsify_block_2d(x, gate, thresh, *, interpret: bool = False):
@@ -51,24 +53,23 @@ def sparsify_block_2d(x, gate, thresh, *, interpret: bool = False):
         x = jnp.pad(x, ((0, rp - r), (0, cp - c)))
         gate = jnp.pad(gate, ((0, rp - r), (0, cp - c)),
                        constant_values=-1.0)
+    nr, nc = rp // br, cp // bc
     y, nnz = pl.pallas_call(
         _sparsify_kernel,
-        grid=(rp // br, cp // bc),
+        grid=(nr, nc),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),     # thresh: whole
             pl.BlockSpec((br, bc), lambda i, j: (i, j)),
             pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((br, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
+        out_specs=[pl.BlockSpec((br, bc), lambda i, j: (i, j)),
+                   lane_row_spec()],
         out_shape=[
             jax.ShapeDtypeStruct((rp, cp), x.dtype),
-            jax.ShapeDtypeStruct((rp // br, cp // bc), jnp.int32),
+            jax.ShapeDtypeStruct((nr, nc, 1, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(jnp.asarray(thresh, jnp.float32).reshape(1, 1), x, gate)
     if (rp, cp) != (r, c):
         y = y[:r, :c]
-    return y, nnz
+    return y, nnz[:, :, 0, 0]

@@ -26,16 +26,10 @@ from jax.sharding import PartitionSpec as P
 from repro.core import compression
 from repro.core import topology as topo
 
-if hasattr(jax, "shard_map"):                           # jax >= 0.6
-    def _shard_map(body, mesh, in_specs, out_specs):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:                                                   # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
 
-    def _shard_map(body, mesh, in_specs, out_specs):
-        return _exp_shard_map(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def _shard_map(body, mesh, in_specs, out_specs):
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def matchings_as_pairs(adj: np.ndarray) -> list[list[tuple[int, int]]]:

@@ -14,7 +14,7 @@ from _hypothesis_compat import given, settings, st
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.consensus_dist import consensus_dist_2d
-from repro.kernels.gossip_mix import gossip_mix_2d
+from repro.kernels.gossip_mix import gossip_mix_2d, gossip_mix_rows
 from repro.kernels.quantize_block import (BLOCK_COLS, BLOCK_ROWS,
                                           dequantize_block_2d,
                                           quantize_block_2d)
@@ -218,6 +218,39 @@ def test_gossip_kernel_matches_dense_gossip(dtype, k, p):
         xi, x2, wi, interpret=True))(x2, jnp.asarray(mix, jnp.float32))
     got = y2.reshape(n_workers, -1)[:, :p]
 
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("n_workers,p", [(6, 1024 * 8), (6, 5000),
+                                         (30, 1000), (4, 300), (9, 1537)])
+def test_gossip_mix_rows_matches_vmapped_mix_2d(dtype, n_workers, p):
+    """The fused engine's dense mix on the [W, P] rows is, element for
+    element, the same arithmetic as ``gossip_mix_2d`` vmapped over the
+    mixing-matrix rows on the [W, rows, 1024] layout: bit-identical,
+    ragged last column tile included, and within tolerance of the
+    reference engine's dense ``_gossip`` (tensordot)."""
+    from repro.core.engine import _gossip
+    mix = jnp.asarray(_mixing_rows(n_workers, 3, seed=p), jnp.float32)
+    x = jax.random.normal(KEY, (n_workers, p), jnp.float32).astype(dtype)
+
+    got = gossip_mix_rows(x, mix, interpret=True)
+
+    cols = min(1024, p)
+    rows = -(-p // cols)
+    x2 = jnp.pad(x, ((0, 0), (0, rows * cols - p))).reshape(
+        n_workers, rows, cols)
+    y2 = jax.vmap(lambda xi, wi: gossip_mix_2d(
+        xi, x2, wi, interpret=True))(x2, mix)
+    assert got.shape == x.shape and got.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(y2.reshape(n_workers, -1)[:, :p],
+                                             np.float32))
+
+    want = _gossip({"w": x}, mix)["w"]
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
