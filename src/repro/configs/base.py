@@ -83,7 +83,7 @@ class ModelConfig:
     within_worker: str = "tp"       # tp | dp: small archs whose head counts
     # don't divide the 16-way model axis replicate params within the worker
     # and split the worker's batch over it instead (DESIGN.md §4)
-    # --- perf knobs (§Perf hillclimb; defaults = paper-faithful baseline) ---
+    # --- perf knobs (defaults = paper-faithful baseline) ---
     serve_seq_shard: bool = False   # sequence parallelism over "model" in
     # serving for within_worker="dp" archs (dedups 16x replicated compute)
     moe_shard_groups: int = 0       # shard-local MoE dispatch: route within

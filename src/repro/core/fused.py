@@ -30,9 +30,9 @@ and ``run_adpsgd``.
   happen on device. The schedule itself is replayed host-side so the
   cluster's RNG stream matches the reference engine draw for draw.
 - ``seeds=jnp.arange(S)`` adds a ``jax.vmap`` axis over model-init /
-  batch-sampling seeds: S experiments amortize one scan (sweep workloads
-  like benchmarks/hillclimb.py). Static-plan strategies only — an
-  adaptive plan is feedback from one seed's trajectory.
+  batch-sampling seeds: S experiments amortize one scan (sweep
+  workloads). Static-plan strategies only — an adaptive plan is feedback
+  from one seed's trajectory.
 - ``cfg.compress`` ("int8" / "topk:<k>" / "randk:<k>") swaps the gossip
   for the codec's compensated update (core/compression.py): per-worker
   error-feedback residuals ride in the scan carry, the wire round trip
@@ -55,6 +55,7 @@ differential harness in ``tests/test_fused_equivalence.py``.
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from functools import partial
 
@@ -166,153 +167,164 @@ def _scan_segment(stacked, err, bx, by, ex, ey, px, py, taus, lrs, mixes,
                 # (W @ v - v): through the edge kernel when sparse (zero-
                 # weight padding edges make no-comm rounds exact no-ops),
                 # dense tensordot otherwise
-                if sparse:
-                    return gossip_edges(v, src_h, dst_h, wgt_h,
-                                        interpret=interpret) - v
-                return jnp.tensordot(mix_h, v, axes=1) - v
+                with jax.named_scope("dfl.mix"):
+                    if sparse:
+                        return gossip_edges(v, src_h, dst_h, wgt_h,
+                                            interpret=interpret) - v
+                    return jnp.tensordot(mix_h, v, axes=1) - v
 
             # --- join re-init: the reference's _reinit_joined with
             # (keep, donor weights) precomputed host-side; an all-False
             # keep_h makes the blend an exact no-op ---
-            carry = _blend_joined(carry, keep_h, rw_h)
-            if stateful:
-                # joined rows adopt a blended model; their codec state
-                # resets the same way as in the reference engine (zeroed
-                # residual / x̂ re-anchored at the blended row)
-                err_c = compression.state_after_join(
-                    err_c, keep_h[:, None], _flatten_workers(carry),
-                    kind, ef)
-            elif leafmap:
-                err_c = compression.leafmap_state_after_join(
-                    err_c, keep_h[:, None], _flatten_workers(carry),
-                    lcodec, ef)
+            with jax.named_scope("dfl.join"):
+                carry = _blend_joined(carry, keep_h, rw_h)
+                if stateful:
+                    # joined rows adopt a blended model; their codec state
+                    # resets the same way as in the reference engine (zeroed
+                    # residual / x̂ re-anchored at the blended row)
+                    err_c = compression.state_after_join(
+                        err_c, keep_h[:, None], _flatten_workers(carry),
+                        kind, ef)
+                elif leafmap:
+                    err_c = compression.leafmap_state_after_join(
+                        err_c, keep_h[:, None], _flatten_workers(carry),
+                        lcodec, ef)
             prev = carry
 
             # --- local updating (Eq. 3), masked to tau_i — the SAME
             # per-worker step function the reference engine vmaps ---
-            carry = jax.vmap(
-                lambda p, bxw, byw, tau: _sgd_worker(adapter, p, bxw, byw,
-                                                     tau, lr_h, tau_cap))(
-                carry, bxh, byh, tau_h)
+            with jax.named_scope("dfl.local_sgd"):
+                carry = jax.vmap(
+                    lambda p, bxw, byw, tau: _sgd_worker(adapter, p, bxw, byw,
+                                                         tau, lr_h, tau_cap))(
+                    carry, bxh, byh, tau_h)
 
-            flat = _flatten_workers(carry)
-            if robust != "none":
-                # --- robust aggregation (core/robust.py lowered): the
-                # wire carries the (possibly corrupted) transmitted copy;
-                # each worker sort-trims its gathered closed neighborhood
-                # through the Pallas gather-sort-trim kernel. No-comm
-                # rounds carry all-zero degrees (keep-own-row) and are
-                # additionally comm_h-gated to the reference's skipped
-                # gossip — an exact no-op either way ---
-                transmitted = (robust_agg.apply_attack(
-                    flat, byz, atk_scale, kind=attack) if attack else flat)
-                mixed = robust_gossip(flat, transmitted, nbr_h, deg_h,
-                                      b=rb, mode=robust,
-                                      interpret=interpret)
-                y_flat = jnp.where(comm_h > 0, mixed, flat)
-            elif attack:
-                # --- plain (non-robust) mixing of a lying wire — the
-                # attacked baseline the robust modes are measured
-                # against: Eq. 5 consumes the transmitted copies ---
-                transmitted = robust_agg.apply_attack(flat, byz, atk_scale,
-                                                      kind=attack)
-                if sparse:
-                    mixed = robust_agg.gossip_byz_edges(
-                        flat, transmitted, src_h, dst_h, wgt_h)
+            # the exchange: robust aggregation, a codec round trip or
+            # the plain mix, each under its own scope (mix_delta nests
+            # "dfl.mix" inside the codec's)
+            phase = ("dfl.robust" if robust != "none" or attack else
+                     "dfl.codec" if leafmap or compress else "dfl.mix")
+            with jax.named_scope(phase):
+                flat = _flatten_workers(carry)
+                if robust != "none":
+                    # --- robust aggregation (core/robust.py lowered): the
+                    # wire carries the (possibly corrupted) transmitted copy;
+                    # each worker sort-trims its gathered closed neighborhood
+                    # through the Pallas gather-sort-trim kernel. No-comm
+                    # rounds carry all-zero degrees (keep-own-row) and are
+                    # additionally comm_h-gated to the reference's skipped
+                    # gossip — an exact no-op either way ---
+                    transmitted = (robust_agg.apply_attack(
+                        flat, byz, atk_scale, kind=attack) if attack else flat)
+                    mixed = robust_gossip(flat, transmitted, nbr_h, deg_h,
+                                          b=rb, mode=robust,
+                                          interpret=interpret)
+                    y_flat = jnp.where(comm_h > 0, mixed, flat)
+                elif attack:
+                    # --- plain (non-robust) mixing of a lying wire — the
+                    # attacked baseline the robust modes are measured
+                    # against: Eq. 5 consumes the transmitted copies ---
+                    transmitted = robust_agg.apply_attack(flat, byz, atk_scale,
+                                                          kind=attack)
+                    if sparse:
+                        mixed = robust_agg.gossip_byz_edges(
+                            flat, transmitted, src_h, dst_h, wgt_h)
+                    else:
+                        mixed = robust_agg.gossip_byz_dense(flat, transmitted,
+                                                            mix_h)
+                    y_flat = jnp.where(comm_h > 0, mixed, flat)
+                elif leafmap:
+                    # --- per-leaf codec map: the SAME shared payload round
+                    # trip as the reference (compression.leafmap_payload),
+                    # one mixing delta on the combined payload, per-segment
+                    # gamma damping, comm_h gating both params and codec
+                    # state to an exact no-op on no-communication rounds ---
+                    payload, new_err = compression.leafmap_payload(
+                        flat, err_c, lcodec, error_feedback=ef, key=skey,
+                        step=h_h)
+                    err_c = jnp.where(comm_h > 0, new_err, err_c)
+                    gmask = jnp.asarray(
+                        compression.leafmap_gamma_mask(lcodec, ef))
+                    gvec = gmask * gamma + (1.0 - gmask)
+                    y_flat = flat + comm_h * gvec[None, :] * mix_delta(payload)
+                elif kind == "topk" and ef:
+                    # --- x̂-tracked top-k (ChocoSGD form, the same update as
+                    # compression.compressed_gossip_ref): the wire carries
+                    # the top-k innovation against the tracked public copy,
+                    # through the Pallas sparsify kernel; the damped
+                    # consensus step mixes the advanced copies. comm_h gates
+                    # no-communication rounds to an exact no-op (nothing is
+                    # sent: neither params nor x̂ move) ---
+                    q = compression.sparsify_rows(flat - err_c, "topk", k,
+                                                  use_kernel=True,
+                                                  interpret=interpret)
+                    xhat = err_c + q
+                    err_c = jnp.where(comm_h > 0, xhat, err_c)
+                    y_flat = flat + comm_h * gamma * mix_delta(xhat)
+                elif compress:
+                    # --- int8 / rand-k / naive top-k: the codec round trip
+                    # of z = x + e per worker through the Pallas kernels on
+                    # the [W, rows, cols] layout (quantize/dequantize or the
+                    # sparsify mask-and-pack), then the same tensordot mixing
+                    # of ŷ as the reference's _gossip_compressed, with comm_h
+                    # gating as above ---
+                    z = flat + err_c if stateful else flat
+                    yhat = compression.encode_rows(z, kind, k, key=skey,
+                                                   step=h_h, use_kernel=True,
+                                                   interpret=interpret)
+                    if stateful:
+                        err_c = jnp.where(comm_h > 0, z - yhat, err_c)
+                    y_flat = flat + comm_h * mix_delta(yhat)
+                elif sparse:
+                    # --- sparse gossip (Eq. 5-6) through the edge kernel on
+                    # [W, P]: y_i = x_i + sum_e w_e (x_src - x_i) over the
+                    # round's directed edges; no-communication rounds carry
+                    # all-zero-weight edges — an exact no-op ---
+                    y_flat = gossip_edges(flat, src_h, dst_h, wgt_h,
+                                          interpret=interpret)
                 else:
-                    mixed = robust_agg.gossip_byz_dense(flat, transmitted,
-                                                        mix_h)
-                y_flat = jnp.where(comm_h > 0, mixed, flat)
-            elif leafmap:
-                # --- per-leaf codec map: the SAME shared payload round
-                # trip as the reference (compression.leafmap_payload),
-                # one mixing delta on the combined payload, per-segment
-                # gamma damping, comm_h gating both params and codec
-                # state to an exact no-op on no-communication rounds ---
-                payload, new_err = compression.leafmap_payload(
-                    flat, err_c, lcodec, error_feedback=ef, key=skey,
-                    step=h_h)
-                err_c = jnp.where(comm_h > 0, new_err, err_c)
-                gmask = jnp.asarray(
-                    compression.leafmap_gamma_mask(lcodec, ef))
-                gvec = gmask * gamma + (1.0 - gmask)
-                y_flat = flat + comm_h * gvec[None, :] * mix_delta(payload)
-            elif kind == "topk" and ef:
-                # --- x̂-tracked top-k (ChocoSGD form, the same update as
-                # compression.compressed_gossip_ref): the wire carries
-                # the top-k innovation against the tracked public copy,
-                # through the Pallas sparsify kernel; the damped
-                # consensus step mixes the advanced copies. comm_h gates
-                # no-communication rounds to an exact no-op (nothing is
-                # sent: neither params nor x̂ move) ---
-                q = compression.sparsify_rows(flat - err_c, "topk", k,
-                                              use_kernel=True,
-                                              interpret=interpret)
-                xhat = err_c + q
-                err_c = jnp.where(comm_h > 0, xhat, err_c)
-                y_flat = flat + comm_h * gamma * mix_delta(xhat)
-            elif compress:
-                # --- int8 / rand-k / naive top-k: the codec round trip
-                # of z = x + e per worker through the Pallas kernels on
-                # the [W, rows, cols] layout (quantize/dequantize or the
-                # sparsify mask-and-pack), then the same tensordot mixing
-                # of ŷ as the reference's _gossip_compressed, with comm_h
-                # gating as above ---
-                z = flat + err_c if stateful else flat
-                yhat = compression.encode_rows(z, kind, k, key=skey,
-                                               step=h_h, use_kernel=True,
-                                               interpret=interpret)
-                if stateful:
-                    err_c = jnp.where(comm_h > 0, z - yhat, err_c)
-                y_flat = flat + comm_h * mix_delta(yhat)
-            elif sparse:
-                # --- sparse gossip (Eq. 5-6) through the edge kernel on
-                # [W, P]: y_i = x_i + sum_e w_e (x_src - x_i) over the
-                # round's directed edges; no-communication rounds carry
-                # all-zero-weight edges — an exact no-op ---
-                y_flat = gossip_edges(flat, src_h, dst_h, wgt_h,
-                                      interpret=interpret)
-            else:
-                # --- gossip (Eq. 5-6) through the Pallas kernel on the
-                # [W, P] rows: y_i = x_i + sum_j w_ij (x_j - x_i) =
-                # sum_j w_ij x_j for a row-stochastic mix; rounds without
-                # communication carry an identity mix, which the kernel
-                # maps to an exact no-op ---
-                y_flat = gossip_mix_rows(flat, mix_h, interpret=interpret)
-            carry = _unflatten(y_flat, carry)
+                    # --- gossip (Eq. 5-6) through the Pallas kernel on the
+                    # [W, P] rows: y_i = x_i + sum_j w_ij (x_j - x_i) =
+                    # sum_j w_ij x_j for a row-stochastic mix; rounds without
+                    # communication carry an identity mix, which the kernel
+                    # maps to an exact no-op ---
+                    y_flat = gossip_mix_rows(flat, mix_h, interpret=interpret)
+                carry = _unflatten(y_flat, carry)
 
             # --- per-round metrics: fleet accuracy/loss over alive
             # workers + consensus distance to the alive mean ---
-            accs = jax.vmap(lambda p: adapter.accuracy(p, tx, ty))(carry)
-            tloss = jax.vmap(
-                lambda p: adapter.loss(p, {"x": tx, "y": ty}))(carry)
-            dmean = jnp.tensordot(cw_h, y_flat, axes=1)
-            dists = jnp.sqrt(jnp.sum((y_flat - dmean[None]) ** 2, axis=1))
-            outs = {"acc": jnp.dot(ew_h, accs),
-                    "loss": jnp.dot(ew_h, tloss),
-                    "consensus": jnp.dot(cw_h, dists)}
+            with jax.named_scope("dfl.evaluation"):
+                accs = jax.vmap(lambda p: adapter.accuracy(p, tx, ty))(carry)
+                tloss = jax.vmap(
+                    lambda p: adapter.loss(p, {"x": tx, "y": ty}))(carry)
+                dmean = jnp.tensordot(cw_h, y_flat, axes=1)
+                dists = jnp.sqrt(jnp.sum((y_flat - dmean[None]) ** 2, axis=1))
+                outs = {"acc": jnp.dot(ew_h, accs),
+                        "loss": jnp.dot(ew_h, tloss),
+                        "consensus": jnp.dot(cw_h, dists)}
 
             if measure:
-                # --- Alg. 1 lines 4-5: the SAME per-worker measurement
-                # function as the reference engine's _measure (eval/probe
-                # tensors passed whole, only params vmapped) ---
-                losses, _, ls, sigs, upds = jax.vmap(
-                    lambda p, q: _measure_worker(adapter, p, q, ex, ey, px,
-                                                 py))(carry, prev)
-                # consensus.pairwise_distances' f32 gram trick, including
-                # its cancellation noise floor for near-identical models —
-                # that floor feeds FedHP's tracker, so it is part of the
-                # behavior being reproduced
-                sq = jnp.sum(y_flat * y_flat, axis=1)
-                d2 = jnp.maximum(
-                    sq[:, None] + sq[None, :] - 2.0 * (y_flat @ y_flat.T),
-                    0.0)
-                d2 = d2 * (1.0 - jnp.eye(d2.shape[0]))
-                outs.update(losses=losses, ls=ls, sigs=sigs, upds=upds,
-                            edge=jnp.sqrt(d2))
-                if needs_cross:
-                    outs["cross"] = _cross_loss_matrix(
-                        adapter, carry, ex[:, :64], ey[:, :64])
+                with jax.named_scope("dfl.alg1_measure"):
+                    # --- Alg. 1 lines 4-5: the SAME per-worker measurement
+                    # function as the reference engine's _measure (eval/probe
+                    # tensors passed whole, only params vmapped) ---
+                    losses, _, ls, sigs, upds = jax.vmap(
+                        lambda p, q: _measure_worker(adapter, p, q, ex, ey, px,
+                                                     py))(carry, prev)
+                    # consensus.pairwise_distances' f32 gram trick, including
+                    # its cancellation noise floor for near-identical models —
+                    # that floor feeds FedHP's tracker, so it is part of the
+                    # behavior being reproduced
+                    sq = jnp.sum(y_flat * y_flat, axis=1)
+                    d2 = jnp.maximum(
+                        sq[:, None] + sq[None, :] - 2.0 * (y_flat @ y_flat.T),
+                        0.0)
+                    d2 = d2 * (1.0 - jnp.eye(d2.shape[0]))
+                    outs.update(losses=losses, ls=ls, sigs=sigs, upds=upds,
+                                edge=jnp.sqrt(d2))
+                    if needs_cross:
+                        outs["cross"] = _cross_loss_matrix(
+                            adapter, carry, ex[:, :64], ey[:, :64])
             return (carry, err_c), outs
 
         return jax.lax.scan(body, (stacked, err),
@@ -388,8 +400,9 @@ def _scan_segment_sharded(stacked, err, bx, by, ex, ey, px, py, taus, lrs,
              keep_h, rw_h, h_h) = xs
 
             def mix_delta(v):
-                return routed_mix_delta(v, sl_h, dl_h, wl_h, offsets, axes,
-                                        n_shards)
+                with jax.named_scope("dfl.mix"):
+                    return routed_mix_delta(v, sl_h, dl_h, wl_h, offsets,
+                                            axes, n_shards)
 
             # --- join re-init: _blend_joined with the fleet mean as a
             # psum of per-shard partial tensordots (rw_h is zero outside
@@ -400,73 +413,81 @@ def _scan_segment_sharded(stacked, err, bx, by, ex, ey, px, py, taus, lrs,
                 kk = keep_h.reshape((-1,) + (1,) * (l.ndim - 1))
                 return jnp.where(kk, mean[None].astype(l.dtype), l)
 
-            carry = jax.tree.map(blend, carry)
-            if stateful:
-                err_c = compression.state_after_join(
-                    err_c, keep_h[:, None], _flatten_workers(carry), kind,
-                    ef)
+            with jax.named_scope("dfl.join"):
+                carry = jax.tree.map(blend, carry)
+                if stateful:
+                    err_c = compression.state_after_join(
+                        err_c, keep_h[:, None], _flatten_workers(carry), kind,
+                        ef)
             prev = carry
 
             # --- local updating (Eq. 3): row-local, the same vmapped
             # per-worker step on each shard's block ---
-            carry = jax.vmap(
-                lambda p, bxw, byw, tau: _sgd_worker(adapter, p, bxw, byw,
-                                                     tau, lr_h, tau_cap))(
-                carry, bxh, byh, tau_h)
+            with jax.named_scope("dfl.local_sgd"):
+                carry = jax.vmap(
+                    lambda p, bxw, byw, tau: _sgd_worker(adapter, p, bxw, byw,
+                                                         tau, lr_h, tau_cap))(
+                    carry, bxh, byh, tau_h)
 
-            flat = _flatten_workers(carry)
-            if kind == "topk" and ef:
-                # x̂-tracked top-k: identical update to the unsharded
-                # scan; the oracle sparsify is per-row, so each shard
-                # compresses its own rows
-                q = compression.sparsify_rows(flat - err_c, "topk", k,
-                                              use_kernel=False)
-                xhat = err_c + q
-                err_c = jnp.where(comm_h > 0, xhat, err_c)
-                y_flat = flat + comm_h * gamma * mix_delta(xhat)
-            elif compress:
-                # int8 / rand-k / naive top-k round trip per shard block
-                # (rand-k's mask is recomputed identically on every shard
-                # from the shared key + step), then the routed delta
-                z = flat + err_c if stateful else flat
-                yhat = compression.encode_rows(z, kind, k, key=skey,
-                                               step=h_h, use_kernel=False)
-                if stateful:
-                    err_c = jnp.where(comm_h > 0, z - yhat, err_c)
-                y_flat = flat + comm_h * mix_delta(yhat)
-            else:
-                # sparse gossip (Eq. 5-6): zero-weight padding edges make
-                # no-comm rounds exact no-ops, same contract as the edge
-                # kernel
-                y_flat = flat + mix_delta(flat)
-            carry = _unflatten(y_flat, carry)
+            # the exchange: a codec round trip under its own scope, or
+            # the plain routed mix (mix_delta opens "dfl.mix" either way)
+            with (jax.named_scope("dfl.codec") if compress
+                  else contextlib.nullcontext()):
+                flat = _flatten_workers(carry)
+                if kind == "topk" and ef:
+                    # x̂-tracked top-k: identical update to the unsharded
+                    # scan; the oracle sparsify is per-row, so each shard
+                    # compresses its own rows
+                    q = compression.sparsify_rows(flat - err_c, "topk", k,
+                                                  use_kernel=False)
+                    xhat = err_c + q
+                    err_c = jnp.where(comm_h > 0, xhat, err_c)
+                    y_flat = flat + comm_h * gamma * mix_delta(xhat)
+                elif compress:
+                    # int8 / rand-k / naive top-k round trip per shard block
+                    # (rand-k's mask is recomputed identically on every shard
+                    # from the shared key + step), then the routed delta
+                    z = flat + err_c if stateful else flat
+                    yhat = compression.encode_rows(z, kind, k, key=skey,
+                                                   step=h_h, use_kernel=False)
+                    if stateful:
+                        err_c = jnp.where(comm_h > 0, z - yhat, err_c)
+                    y_flat = flat + comm_h * mix_delta(yhat)
+                else:
+                    # sparse gossip (Eq. 5-6): zero-weight padding edges make
+                    # no-comm rounds exact no-ops, same contract as the edge
+                    # kernel
+                    y_flat = flat + mix_delta(flat)
+                carry = _unflatten(y_flat, carry)
 
             # --- per-round fleet metrics: per-shard partial dots, psum'd
             # (metric weights are zero on the inert padding rows) ---
-            accs = jax.vmap(lambda p: adapter.accuracy(p, tx, ty))(carry)
-            tloss = jax.vmap(
-                lambda p: adapter.loss(p, {"x": tx, "y": ty}))(carry)
-            dmean = jax.lax.psum(jnp.tensordot(cw_h, y_flat, axes=1), axes)
-            dists = jnp.sqrt(jnp.sum((y_flat - dmean[None]) ** 2, axis=1))
-            outs = {"acc": jax.lax.psum(jnp.dot(ew_h, accs), axes),
-                    "loss": jax.lax.psum(jnp.dot(ew_h, tloss), axes),
-                    "consensus": jax.lax.psum(jnp.dot(cw_h, dists), axes)}
+            with jax.named_scope("dfl.evaluation"):
+                accs = jax.vmap(lambda p: adapter.accuracy(p, tx, ty))(carry)
+                tloss = jax.vmap(
+                    lambda p: adapter.loss(p, {"x": tx, "y": ty}))(carry)
+                dmean = jax.lax.psum(jnp.tensordot(cw_h, y_flat, axes=1), axes)
+                dists = jnp.sqrt(jnp.sum((y_flat - dmean[None]) ** 2, axis=1))
+                outs = {"acc": jax.lax.psum(jnp.dot(ew_h, accs), axes),
+                        "loss": jax.lax.psum(jnp.dot(ew_h, tloss), axes),
+                        "consensus": jax.lax.psum(jnp.dot(cw_h, dists), axes)}
 
             if measure:
-                # per-worker measurements are row-local (the eval/probe
-                # stacks are replicated — historical full-stack
-                # semantics); the [W, W] gram needs every row, so the
-                # flat matrix is all_gathered once per measured round
-                losses, _, ls, sigs, upds = jax.vmap(
-                    lambda p, q: _measure_worker(adapter, p, q, ex, ey, px,
-                                                 py))(carry, prev)
-                yg = jax.lax.all_gather(y_flat, axes, axis=0, tiled=True)
-                sq = jnp.sum(yg * yg, axis=1)
-                d2 = jnp.maximum(
-                    sq[:, None] + sq[None, :] - 2.0 * (yg @ yg.T), 0.0)
-                d2 = d2 * (1.0 - jnp.eye(d2.shape[0]))
-                outs.update(losses=losses, ls=ls, sigs=sigs, upds=upds,
-                            edge=jnp.sqrt(d2))
+                with jax.named_scope("dfl.alg1_measure"):
+                    # per-worker measurements are row-local (the eval/probe
+                    # stacks are replicated — historical full-stack
+                    # semantics); the [W, W] gram needs every row, so the
+                    # flat matrix is all_gathered once per measured round
+                    losses, _, ls, sigs, upds = jax.vmap(
+                        lambda p, q: _measure_worker(adapter, p, q, ex, ey, px,
+                                                     py))(carry, prev)
+                    yg = jax.lax.all_gather(y_flat, axes, axis=0, tiled=True)
+                    sq = jnp.sum(yg * yg, axis=1)
+                    d2 = jnp.maximum(
+                        sq[:, None] + sq[None, :] - 2.0 * (yg @ yg.T), 0.0)
+                    d2 = d2 * (1.0 - jnp.eye(d2.shape[0]))
+                    outs.update(losses=losses, ls=ls, sigs=sigs, upds=upds,
+                                edge=jnp.sqrt(d2))
             return (carry, err_c), outs
 
         return jax.lax.scan(body, (stacked, err),
@@ -572,7 +593,8 @@ def _precompute_segment(h0: int, seg_len: int, cluster: SimCluster,
         mu = cluster.sample_mu()
         beta = cluster.sample_beta()
         if plan is None or not adaptive:
-            plan = strategy.plan(h, alive=alive)
+            with jax.profiler.TraceAnnotation("dfl.plan", h=h):
+                plan = strategy.plan(h, alive=alive)
         rcodec = plan.codec if plan.codec is not None else codec0
         if codec0.kind == "leafmap" and rcodec.kind == "leafmap":
             rcodec = codec0           # the compiled copy
@@ -850,77 +872,80 @@ def run_dfl_fused(data: Dataset, test_x, test_y, shards,
     interp = (jax.default_backend() == "cpu") if interpret is None \
         else interpret
 
-    # per-seed setup, consuming each seed's RNG exactly like run_dfl
-    rngs = [np.random.default_rng(s) for s in seed_list]
-    stacked0, exs, eys = [], [], []
-    for s, rng in zip(seed_list, rngs):
-        if init_params is not None:
-            stacked0.append(jax.tree.map(jnp.asarray, init_params))
+    # host spans (dfl.*) put the control plane on the profiler's clock
+    # beside the round programs' device scopes
+    with jax.profiler.TraceAnnotation("dfl.init", h=0):
+        # per-seed setup, consuming each seed's RNG exactly like run_dfl
+        rngs = [np.random.default_rng(s) for s in seed_list]
+        stacked0, exs, eys = [], [], []
+        for s, rng in zip(seed_list, rngs):
+            if init_params is not None:
+                stacked0.append(jax.tree.map(jnp.asarray, init_params))
+            else:
+                key = jax.random.PRNGKey(s)
+                p0 = adapter.init(key)
+                stacked0.append(jax.tree.map(
+                    lambda l: jnp.broadcast_to(l, (n,) + l.shape), p0))
+            exs.append(np.stack([data.x[sh[rng.integers(0, len(sh), 256)]]
+                                 for sh in shards]))
+            eys.append(np.stack([data.y[sh[rng.integers(0, len(sh), 256)]]
+                                 for sh in shards]))
+        plan = None
+        if sharded:
+            from repro.runtime import shardexec
+            plan = shardexec.WorkerShardPlan(
+                mesh if mesh is not None else shardexec.default_worker_mesh(),
+                n)
+            # one lane, padded to w_pad inert rows and committed to the mesh
+            # (no leading seed axis — the scan runs S=1)
+            stacked = plan.put_stacked(stacked0[0])
         else:
-            key = jax.random.PRNGKey(s)
-            p0 = adapter.init(key)
-            stacked0.append(jax.tree.map(
-                lambda l: jnp.broadcast_to(l, (n,) + l.shape), p0))
-        exs.append(np.stack([data.x[sh[rng.integers(0, len(sh), 256)]]
-                             for sh in shards]))
-        eys.append(np.stack([data.y[sh[rng.integers(0, len(sh), 256)]]
-                             for sh in shards]))
-    plan = None
-    if sharded:
-        from repro.runtime import shardexec
-        plan = shardexec.WorkerShardPlan(
-            mesh if mesh is not None else shardexec.default_worker_mesh(),
-            n)
-        # one lane, padded to w_pad inert rows and committed to the mesh
-        # (no leading seed axis — the scan runs S=1)
-        stacked = plan.put_stacked(stacked0[0])
-    else:
-        stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *stacked0)
-    codec0 = compression.parse_mode(cfg.compress)
-    if codec0.kind == "leafmap":
-        codec0 = codec0.compile(adapter.leaf_offsets())
-    leafmap = codec0.kind == "leafmap"
-    if sharded and leafmap:
-        raise ValueError(
-            "per-leaf codec maps are single-device only: their shared "
-            "payload spans leaf segments, which would need per-segment "
-            "routing tables on the sharded path")
-    compress = codec0.kind != "none"
-    if robust_active and compress:
-        raise ValueError(
-            "cfg.byzantine / cfg.robust do not compose with cfg.compress")
-    p_model = adapter.param_count
-    # rand-k mask stream: derived from cfg.seed (not the lane seeds) so
-    # vmapped lanes share the masks like they share the rest of the
-    # host-side control plane
-    skey = compression.sparsify_base_key(cfg.seed)
-    # per-seed codec state (int8 residual / top-k x̂ / leafmap segment
-    # buffer), carried across segments; a [S, W, 1] dummy keeps the carry
-    # structure static for stateless runs (uncompressed, rand-k, EF off)
-    # without hauling a dead fleet-sized buffer through the scan
-    if leafmap:
-        err = compression.leafmap_state_init(
-            jnp.stack([_flatten_workers(s) for s in stacked0]),
-            codec0, cfg.error_feedback)
-    elif compress and compression.carries_state(codec0.kind,
-                                                cfg.error_feedback):
-        # sharded: state rows follow the padded [w_pad, P] layout (the
-        # inert rows' zero params give zero residual / zero x̂)
-        err = (compression.state_init(_flatten_workers(stacked),
-                                      codec0.kind, cfg.error_feedback)
-               if plan is not None else
-               compression.state_init(
-                   jnp.stack([_flatten_workers(s) for s in stacked0]),
-                   codec0.kind, cfg.error_feedback))
-    elif plan is not None:
-        err = jnp.zeros((plan.w_pad, 1), jnp.float32)
-    else:
-        err = jnp.zeros((len(seed_list), n, 1), jnp.float32)
-    ex = jnp.asarray(np.stack(exs))
-    ey = jnp.asarray(np.stack(eys))
-    px, py = ex[:, :, :32], ey[:, :, :32]
-    tx = jnp.asarray(test_x[:eval_subset])
-    ty = jnp.asarray(test_y[:eval_subset])
+            stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *stacked0)
+        codec0 = compression.parse_mode(cfg.compress)
+        if codec0.kind == "leafmap":
+            codec0 = codec0.compile(adapter.leaf_offsets())
+        leafmap = codec0.kind == "leafmap"
+        if sharded and leafmap:
+            raise ValueError(
+                "per-leaf codec maps are single-device only: their shared "
+                "payload spans leaf segments, which would need per-segment "
+                "routing tables on the sharded path")
+        compress = codec0.kind != "none"
+        if robust_active and compress:
+            raise ValueError(
+                "cfg.byzantine / cfg.robust do not compose with cfg.compress")
+        p_model = adapter.param_count
+        # rand-k mask stream: derived from cfg.seed (not the lane seeds) so
+        # vmapped lanes share the masks like they share the rest of the
+        # host-side control plane
+        skey = compression.sparsify_base_key(cfg.seed)
+        # per-seed codec state (int8 residual / top-k x̂ / leafmap segment
+        # buffer), carried across segments; a [S, W, 1] dummy keeps the carry
+        # structure static for stateless runs (uncompressed, rand-k, EF off)
+        # without hauling a dead fleet-sized buffer through the scan
+        if leafmap:
+            err = compression.leafmap_state_init(
+                jnp.stack([_flatten_workers(s) for s in stacked0]),
+                codec0, cfg.error_feedback)
+        elif compress and compression.carries_state(codec0.kind,
+                                                    cfg.error_feedback):
+            # sharded: state rows follow the padded [w_pad, P] layout (the
+            # inert rows' zero params give zero residual / zero x̂)
+            err = (compression.state_init(_flatten_workers(stacked),
+                                          codec0.kind, cfg.error_feedback)
+                   if plan is not None else
+                   compression.state_init(
+                       jnp.stack([_flatten_workers(s) for s in stacked0]),
+                       codec0.kind, cfg.error_feedback))
+        elif plan is not None:
+            err = jnp.zeros((plan.w_pad, 1), jnp.float32)
+        else:
+            err = jnp.zeros((len(seed_list), n, 1), jnp.float32)
+        ex = jnp.asarray(np.stack(exs))
+        ey = jnp.asarray(np.stack(eys))
+        px, py = ex[:, :, :32], ey[:, :, :32]
+        tx = jnp.asarray(test_x[:eval_subset])
+        ty = jnp.asarray(test_y[:eval_subset])
 
     mixfn = (topo.mixing_matrix_metropolis if mixing == "metropolis"
              else topo.mixing_matrix_uniform)
@@ -936,93 +961,113 @@ def run_dfl_fused(data: Dataset, test_x, test_y, shards,
     h = 0
     stop = False
     while h < rounds and not stop:
-        seg_len = (min(replan, rounds - h) if adaptive
-                   else min(rounds - h, MAX_FUSE_ROUNDS))
-        seg, clock, stop = _precompute_segment(
-            h, seg_len, cluster, strategy, cfg, rngs, data, shards, mixfn,
-            clock, time_budget, adaptive, codec0, p_model, sparse=sparse,
-            mixing=mixing, byz=byz if has_byz else None,
-            robust=robust_mode in ("trimmed", "median"))
-        if plan is not None:
-            offsets, esl, edl, ewl = _sharded_edge_tables(seg, plan)
-            pd = plan.pad
-            (stacked, err), outs = _scan_segment_sharded(
-                stacked, err,
-                jnp.asarray(_pad_rows(seg.bx[0], pd)),
-                jnp.asarray(_pad_rows(seg.by[0], pd)),
-                ex[0], ey[0], px[0], py[0],
-                jnp.asarray(_pad_rows(seg.taus, pd)),
-                jnp.asarray(seg.lrs),
-                jnp.asarray(esl), jnp.asarray(edl), jnp.asarray(ewl),
-                jnp.asarray(seg.comms),
-                jnp.asarray(_pad_rows(seg.ew, pd)),
-                jnp.asarray(_pad_rows(seg.cw, pd)),
-                jnp.asarray(_pad_rows(seg.keep, pd)),
-                jnp.asarray(_pad_rows(seg.rw, pd)),
-                jnp.asarray(seg.hs), skey, jnp.float32(cfg.sparse_gamma),
-                tx, ty, adapter=adapter, tau_cap=seg.tau_cap,
-                measure=adaptive, kind=seg.codec.kind,
-                k=seg.codec.resolve_k(p_model), ef=cfg.error_feedback,
-                mesh=plan.mesh, axes=plan.axes, offsets=offsets,
-                n_shards=plan.n_shards)
-            outs = {k2: np.asarray(v) for k2, v in outs.items()}
-            # slice the inert padding rows off, then re-add the S=1 seed
-            # axis the record/observe loops below index with si=0
-            for k2 in ("losses", "ls", "sigs", "upds"):
-                if k2 in outs:
-                    outs[k2] = outs[k2][:, :n]
-            if "edge" in outs:
-                outs["edge"] = outs["edge"][:, :n, :n]
-            outs = {k2: v[None] for k2, v in outs.items()}
-        else:
-            (stacked, err), outs = _scan_segment(
-                stacked, err, jnp.asarray(seg.bx), jnp.asarray(seg.by),
-                ex, ey,
-                px, py, jnp.asarray(seg.taus), jnp.asarray(seg.lrs),
-                jnp.asarray(seg.mixes), jnp.asarray(seg.esrc),
-                jnp.asarray(seg.edst), jnp.asarray(seg.ewt),
-                jnp.asarray(seg.comms),
-                jnp.asarray(seg.ew), jnp.asarray(seg.cw),
-                jnp.asarray(seg.keep), jnp.asarray(seg.rw),
-                jnp.asarray(seg.hs), jnp.asarray(seg.nbrs),
-                jnp.asarray(seg.degs), jnp.asarray(byz),
-                jnp.float32(atk_scale), skey,
-                jnp.float32(cfg.sparse_gamma),
-                tx, ty, adapter=adapter, tau_cap=seg.tau_cap,
-                measure=adaptive,
-                needs_cross=needs_cross, interpret=interp,
-                kind=seg.codec.kind,
-                k=seg.codec.resolve_k(p_model),
-                ef=cfg.error_feedback, sparse=sparse,
-                lcodec=seg.codec if leafmap else None,
-                robust=robust_mode, rb=robust_b,
-                attack=atk_kind if has_byz else "")
-            outs = {k: np.asarray(v) for k, v in outs.items()}
+        with jax.profiler.StepTraceAnnotation("dfl.segment", step_num=h):
+            seg_len = (min(replan, rounds - h) if adaptive
+                       else min(rounds - h, MAX_FUSE_ROUNDS))
+            with jax.profiler.TraceAnnotation("dfl.precompute", h=h):
+                seg, clock, stop = _precompute_segment(
+                    h, seg_len, cluster, strategy, cfg, rngs, data, shards,
+                    mixfn, clock, time_budget, adaptive, codec0, p_model,
+                    sparse=sparse, mixing=mixing,
+                    byz=byz if has_byz else None,
+                    robust=robust_mode in ("trimmed", "median"))
+                if plan is not None:
+                    offsets, esl, edl, ewl = _sharded_edge_tables(seg, plan)
+            if plan is not None:
+                pd = plan.pad
+                with jax.profiler.TraceAnnotation("dfl.upload", h=h):
+                    (bx, by, taus, lrs, esl, edl, ewl, comms, ew, cw, keep,
+                     rw, hs) = (jnp.asarray(a) for a in (
+                         _pad_rows(seg.bx[0], pd), _pad_rows(seg.by[0], pd),
+                         _pad_rows(seg.taus, pd), seg.lrs, esl, edl, ewl,
+                         seg.comms, _pad_rows(seg.ew, pd),
+                         _pad_rows(seg.cw, pd), _pad_rows(seg.keep, pd),
+                         _pad_rows(seg.rw, pd), seg.hs))
+                    gamma = jnp.float32(cfg.sparse_gamma)
+                with jax.profiler.TraceAnnotation("dfl.dispatch", h=h):
+                    (stacked, err), outs = _scan_segment_sharded(
+                        stacked, err, bx, by, ex[0], ey[0], px[0], py[0],
+                        taus, lrs, esl, edl, ewl, comms, ew, cw, keep, rw,
+                        hs, skey, gamma, tx, ty, adapter=adapter,
+                        tau_cap=seg.tau_cap, measure=adaptive,
+                        kind=seg.codec.kind,
+                        k=seg.codec.resolve_k(p_model),
+                        ef=cfg.error_feedback, mesh=plan.mesh,
+                        axes=plan.axes, offsets=offsets,
+                        n_shards=plan.n_shards)
+                # the running program holds its inputs until it ends;
+                # dropping ours lets them go then, not at the next segment
+                del bx, by, taus, lrs, esl, edl, ewl, comms, ew, cw, keep
+                del rw, hs
+                with jax.profiler.TraceAnnotation("dfl.sync", h=h):
+                    outs = {k2: np.asarray(v) for k2, v in outs.items()}
+                # slice the inert padding rows off, then re-add the S=1
+                # seed axis the record/observe loops below index with si=0
+                for k2 in ("losses", "ls", "sigs", "upds"):
+                    if k2 in outs:
+                        outs[k2] = outs[k2][:, :n]
+                if "edge" in outs:
+                    outs["edge"] = outs["edge"][:, :n, :n]
+                outs = {k2: v[None] for k2, v in outs.items()}
+            else:
+                with jax.profiler.TraceAnnotation("dfl.upload", h=h):
+                    (bx, by, taus, lrs, mixes, esrc, edst, ewt, comms, ew,
+                     cw, keep, rw, hs, nbrs, degs, byz_d) = (
+                         jnp.asarray(a) for a in (
+                             seg.bx, seg.by, seg.taus, seg.lrs, seg.mixes,
+                             seg.esrc, seg.edst, seg.ewt, seg.comms, seg.ew,
+                             seg.cw, seg.keep, seg.rw, seg.hs, seg.nbrs,
+                             seg.degs, byz))
+                    scale = jnp.float32(atk_scale)
+                    gamma = jnp.float32(cfg.sparse_gamma)
+                with jax.profiler.TraceAnnotation("dfl.dispatch", h=h):
+                    (stacked, err), outs = _scan_segment(
+                        stacked, err, bx, by, ex, ey, px, py, taus, lrs,
+                        mixes, esrc, edst, ewt, comms, ew, cw, keep, rw, hs,
+                        nbrs, degs, byz_d, scale, skey, gamma, tx, ty,
+                        adapter=adapter, tau_cap=seg.tau_cap,
+                        measure=adaptive, needs_cross=needs_cross,
+                        interpret=interp, kind=seg.codec.kind,
+                        k=seg.codec.resolve_k(p_model),
+                        ef=cfg.error_feedback, sparse=sparse,
+                        lcodec=seg.codec if leafmap else None,
+                        robust=robust_mode, rb=robust_b,
+                        attack=atk_kind if has_byz else "")
+                del bx, by, taus, lrs, mixes, esrc, edst, ewt, comms, ew, cw
+                del keep, rw, hs, nbrs, degs, byz_d
+                with jax.profiler.TraceAnnotation("dfl.sync", h=h):
+                    outs = {k: np.asarray(v) for k, v in outs.items()}
 
-        for t in range(len(seg)):
-            hh = h + t
-            for si, hist in enumerate(hists):
-                hist.records.append(RoundRecord(
-                    round=hh, round_time=seg.round_time[t],
-                    waiting_time=seg.waiting[t],
-                    accuracy=float(outs["acc"][si, t]),
-                    loss=float(outs["loss"][si, t]),
-                    mean_tau=seg.mean_tau[t], num_links=seg.num_links[t],
-                    consensus=float(outs["consensus"][si, t]),
-                    cumulative_time=seg.cum_time[t]))
-            if adaptive:
-                a = seg.alive[t]
-                m = seg.meas[t]     # honest alive workers (== a sans byz)
-                strategy.observe(
-                    hh, adj=seg.adjs[t], mu=seg.mus[t], beta=seg.betas[t],
-                    edge_dist=np.asarray(outs["edge"][0, t], np.float64),
-                    update_norms=outs["upds"][0, t][m] if m.any() else [0.0],
-                    smooth_l=float(np.median(outs["ls"][0, t][m])),
-                    sigma=float(np.median(outs["sigs"][0, t][m])),
-                    loss=float(np.mean(outs["losses"][0, t][m])),
-                    cross_loss=np.asarray(outs["cross"][0, t], np.float64)
-                    if needs_cross else None,
-                    alive=a, wire_ratio=seg.wire_ratio[t])
+            with jax.profiler.TraceAnnotation("dfl.observe", h=h):
+                for t in range(len(seg)):
+                    hh = h + t
+                    for si, hist in enumerate(hists):
+                        hist.records.append(RoundRecord(
+                            round=hh, round_time=seg.round_time[t],
+                            waiting_time=seg.waiting[t],
+                            accuracy=float(outs["acc"][si, t]),
+                            loss=float(outs["loss"][si, t]),
+                            mean_tau=seg.mean_tau[t],
+                            num_links=seg.num_links[t],
+                            consensus=float(outs["consensus"][si, t]),
+                            cumulative_time=seg.cum_time[t]))
+                    if adaptive:
+                        a = seg.alive[t]
+                        m = seg.meas[t]  # honest alive workers (a sans byz)
+                        strategy.observe(
+                            hh, adj=seg.adjs[t], mu=seg.mus[t],
+                            beta=seg.betas[t],
+                            edge_dist=np.asarray(outs["edge"][0, t],
+                                                 np.float64),
+                            update_norms=(outs["upds"][0, t][m] if m.any()
+                                          else [0.0]),
+                            smooth_l=float(np.median(outs["ls"][0, t][m])),
+                            sigma=float(np.median(outs["sigs"][0, t][m])),
+                            loss=float(np.mean(outs["losses"][0, t][m])),
+                            cross_loss=np.asarray(outs["cross"][0, t],
+                                                  np.float64)
+                            if needs_cross else None,
+                            alive=a, wire_ratio=seg.wire_ratio[t])
         h += len(seg)
     for si, hist in enumerate(hists):
         # sharded: one lane, no seed axis — hand back the real W rows
