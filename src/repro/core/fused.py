@@ -15,9 +15,11 @@ and ``run_adpsgd``.
   the reference; larger segments freeze (A^h, tau^h) within a segment —
   a documented behavioral deviation bought for throughput (README.md).
 - Gossip (Eq. 5-6) runs through the Pallas ``gossip_mix_rows`` kernel
-  on the flattened [W, P] parameter matrix as it lies (column tiles, all
-  worker rows resident), so P need not be a tile multiple and no
-  relayout feeds the kernel.
+  on the flattened [W, P] parameter matrix as it lies (column tiles as
+  wide as a fixed VMEM budget allows at W rows, all worker rows
+  resident), so P need not be a tile multiple and no relayout or
+  padded copy feeds the kernel; the edge-list kernel below tiles the
+  same way.
 - ``cfg.gossip == "sparse"`` swaps the dense [W, W] mixing for the
   edge-list path: per-round directed edge arrays (padded to a static
   E_max with zero-weight no-op edges) ride the scan instead of [K, W, W]

@@ -14,7 +14,8 @@ from _hypothesis_compat import given, settings, st
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.consensus_dist import consensus_dist_2d
-from repro.kernels.gossip_mix import gossip_mix_2d, gossip_mix_rows
+from repro.kernels.gossip_mix import (ROW_TILE_VMEM, gossip_mix_2d,
+                                      gossip_mix_rows, row_tile_cols)
 from repro.kernels.quantize_block import (BLOCK_COLS, BLOCK_ROWS,
                                           dequantize_block_2d,
                                           quantize_block_2d)
@@ -226,7 +227,8 @@ def test_gossip_kernel_matches_dense_gossip(dtype, k, p):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("n_workers,p", [(6, 1024 * 8), (6, 5000),
-                                         (30, 1000), (4, 300), (9, 1537)])
+                                         (30, 1000), (4, 300), (9, 1537),
+                                         (12, 70_001)])  # 3 tiles, ragged
 def test_gossip_mix_rows_matches_vmapped_mix_2d(dtype, n_workers, p):
     """The fused engine's dense mix on the [W, P] rows is, element for
     element, the same arithmetic as ``gossip_mix_2d`` vmapped over the
@@ -255,6 +257,23 @@ def test_gossip_mix_rows_matches_vmapped_mix_2d(dtype, n_workers, p):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("n_workers,want", [(4, 65_536), (12, 32_768),
+                                            (30, 16_384), (2048, 256)])
+def test_row_tile_cols_fills_the_vmem_budget(n_workers, want):
+    """The column tile of the row-layout kernels: whole 128-lane tiles
+    whose double-buffered input and output blocks, rows padded to 8
+    sublanes, fit the budget, as wide as that allows and never wider
+    than the array."""
+    p = 51_126_720
+    bc = row_tile_cols(n_workers, p, 4)
+    assert bc == want and bc % 128 == 0
+    rows = -(-n_workers // 8) * 8
+    assert 2 * 2 * rows * bc * 4 <= ROW_TILE_VMEM
+    assert 2 * 2 * rows * (bc + 128) * 4 > ROW_TILE_VMEM
+    assert row_tile_cols(n_workers, 100, 4) == 100
+    assert row_tile_cols(n_workers, bc, 4) == bc
 
 
 @pytest.mark.parametrize("shape", [(12, 1000), (7, 1024), (5, 100),

@@ -205,6 +205,51 @@ def test_gossip_edges_kernel_zero_weight_edges_are_noops():
     np.testing.assert_array_equal(y, x)
 
 
+def _numpy_edge_walk(x, src, dst, w):
+    """The edge kernel's arithmetic in NumPy float32: y starts at x and
+    each edge, in table order, adds w_e (x[src_e] - x[dst_e]) to its
+    destination row."""
+    y = x.copy()
+    for s, d, we in zip(src, dst, w):
+        y[d] = y[d] + we * (x[s] - x[d])
+    return y
+
+
+# W=12 at 70,001 columns: the tile rule gives 32,768 columns, so three
+# tiles, the last one ragged
+MULTI_TILE = (12, 70_001)
+
+
+def test_gossip_edges_multi_tile_matches_numpy_walk_bit_for_bit():
+    rng = np.random.default_rng(9)
+    n, p = MULTI_TILE
+    e = 40                  # repeated destinations: the order matters
+    src = rng.integers(0, n, e).astype(np.int32)
+    dst = rng.integers(0, n, e).astype(np.int32)
+    # powers of two make each product w_e (x_s - x_d) exact, so the walk
+    # rounds the same whether or not a backend fuses the multiply-add
+    # (XLA's CPU backend does, NumPy does not)
+    w = (2.0 ** -rng.integers(1, 5, e)).astype(np.float32)
+    x = rng.standard_normal((n, p)).astype(np.float32)
+    y = np.asarray(gossip_edges(jnp.asarray(x), jnp.asarray(src),
+                                jnp.asarray(dst), jnp.asarray(w),
+                                interpret=True))
+    assert y.shape == x.shape and y.dtype == x.dtype
+    np.testing.assert_array_equal(y, _numpy_edge_walk(x, src, dst, w))
+
+
+def test_gossip_edges_multi_tile_zero_weights_return_x():
+    rng = np.random.default_rng(10)
+    n, p = MULTI_TILE
+    x = rng.standard_normal((n, p)).astype(np.float32)
+    src = jnp.asarray(rng.integers(0, n, 24), jnp.int32)
+    dst = jnp.asarray(rng.integers(0, n, 24), jnp.int32)
+    y = np.asarray(gossip_edges(jnp.asarray(x), src, dst,
+                                jnp.zeros(24, jnp.float32), interpret=True))
+    assert y.shape == x.shape
+    np.testing.assert_array_equal(y, x)
+
+
 def test_pad_edges_pads_to_multiple_with_noop_rows():
     src, dst, w = (np.array([1, 2, 3]), np.array([0, 1, 2]),
                    np.array([0.1, 0.2, 0.3], np.float32))
