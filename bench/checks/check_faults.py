@@ -10,11 +10,14 @@ planted in the program:
   of its batch only;
 - ``loss_altered``: the round program's fleet loss comes out 1 % high;
 - ``clock_altered``: the simulated round time the host records comes
-  out high by a millionth.
+  out high by a millionth;
+- ``exchange_dropped`` (a cell whose fleet spans several chips): the
+  gossip leaves out the exchange between chips, so each chip mixes only
+  the edges inside its own rows.
 
 The clean run must read ``correct`` true and every faulty run false.
-(Leaving out the exchange between chips is a fault only of a cell on
-several chips; no cell here has one.)
+Where a selected cell spans several chips, the process runs on that many
+forced host devices, so that the cell runs its sharded path.
 
     JAX_PLATFORMS=cpu python3 bench/checks/check_faults.py [--workload <cell>]
 """
@@ -22,6 +25,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
 from pathlib import Path
@@ -43,6 +47,7 @@ def planted(fault: str):
 
     from repro.core import fused
     sgd, seg = fused._sgd_worker, fused._scan_segment
+    seg_sharded, mix = fused._scan_segment_sharded, fused.routed_mix_delta
     pre = fused._precompute_segment
 
     if fault == "state_unchanged":
@@ -54,10 +59,20 @@ def planted(fault: str):
                        tau_max)
         fused._sgd_worker = half
     elif fault == "loss_altered":
-        def altered(*args, **kw):
-            carry, outs = seg(*args, **kw)
-            return carry, {**outs, "loss": outs["loss"] * jnp.float32(1.01)}
-        fused._scan_segment = altered
+        def altering(program):
+            def altered(*args, **kw):
+                carry, outs = program(*args, **kw)
+                return carry, {**outs,
+                               "loss": outs["loss"] * jnp.float32(1.01)}
+            return altered
+        fused._scan_segment = altering(seg)
+        fused._scan_segment_sharded = altering(seg_sharded)
+    elif fault == "exchange_dropped":
+        def own_rows_only(v, sl, dl, wl, offsets, axes, n_shards):
+            i = offsets.index(0)
+            return mix(v, sl[i:i + 1], dl[i:i + 1], wl[i:i + 1], (0,), axes,
+                       n_shards)
+        fused.routed_mix_delta = own_rows_only
     elif fault == "clock_altered":
         def late(*args, **kw):
             segment, clock, stop = pre(*args, **kw)
@@ -70,6 +85,8 @@ def planted(fault: str):
 
     def undo():
         fused._sgd_worker, fused._scan_segment = sgd, seg
+        fused._scan_segment_sharded = seg_sharded
+        fused.routed_mix_delta = mix
         fused._precompute_segment = pre
         jax.clear_caches()
     return undo
@@ -81,11 +98,21 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     names = args.workload or [w["name"] for w in json.loads(
         (run.ROOT / "BENCHMARK.json").read_text())["workloads"]]
+    cells = [tiny.tiny(name) for name in names]
+    chips = max(cell.chips for cell in cells)
+    if chips > 1:
+        # before JAX starts: a cell over several chips runs sharded
+        os.environ["XLA_FLAGS"] = (f"{os.environ.get('XLA_FLAGS', '')} "
+                                   f"--xla_force_host_platform_device_count="
+                                   f"{chips}")
     bad = []
-    for name in names:
-        cell = tiny.tiny(name)
-        for fault in ("clean", "state_unchanged", "half_batch",
-                      "loss_altered", "clock_altered"):
+    for cell in cells:
+        name = cell.name
+        faults = ["clean", "state_unchanged", "half_batch", "loss_altered",
+                  "clock_altered"]
+        if cell.traffic["mesh_chips"] > 1:
+            faults.append("exchange_dropped")
+        for fault in faults:
             undo = planted(fault)
             try:
                 res = run.run_cell(cell, SEED, 0.0, False, CPU,

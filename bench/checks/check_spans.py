@@ -26,6 +26,7 @@ HERE = Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE.parents[1] / "src"), str(HERE.parent)]
 
 import numpy as np  # noqa: E402
+from jax.profiler import ProfileData  # noqa: E402
 
 import counts  # noqa: E402
 import phases  # noqa: E402
@@ -66,7 +67,9 @@ def record() -> None:
 def load_cpu_run() -> "traces.TraceRun":
     """The kept CPU trace as the readers see a chip's: the XLA CPU
     threads as the device, the other host lines as the host."""
-    pd = traces.load_profile(str(KEPT.parent))
+    # the kept file by name: both kept traces share the directory and
+    # a checkout gives them one mtime
+    pd = ProfileData.from_file(str(KEPT))
     host = [e for v in traces.line_events(
         pd, lambda n: n.startswith(traces.HOST_PLANE),
         lambda n: not n.startswith("tf_")).values() for e in v]

@@ -22,6 +22,7 @@ HERE = Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE.parents[1] / "src"), str(HERE.parent)]
 
 import numpy as np  # noqa: E402
+from jax.profiler import ProfileData  # noqa: E402
 
 import traces  # noqa: E402
 
@@ -53,7 +54,9 @@ def record() -> None:
 
 
 def check() -> None:
-    pd = traces.load_profile(str(KEPT.parent))
+    # the kept file by name: both kept traces share the directory and
+    # a checkout gives them one mtime
+    pd = ProfileData.from_file(str(KEPT))
     host_lines = traces.line_events(
         pd, lambda n: n.startswith(traces.HOST_PLANE),
         lambda n: not n.startswith("tf_"))
@@ -84,10 +87,38 @@ def check() -> None:
     labelled = traces.label_gaps(traces.gaps(dev, lo, hi),
                                  traces.clip(host, lo, hi))
     assert sum(labelled.values()) == idle, (labelled, idle)
+    check_labels_by_hand()
     print(f"ok: window {(hi - lo) / 1e6:.3f} ms, {len(dev)} operations, "
           f"busy {busy / 1e6:.3f} ms (grid {busy_hand / 1e6:.3f} ms), "
           f"idle {idle / 1e6:.3f} ms by host event "
           f"{ {k: round(v / 1e6, 3) for k, v in labelled.items()} }")
+
+
+def check_labels_by_hand(cases: int = 300) -> None:
+    """``traces.label_gaps`` against a scan of every host event for every
+    gap, on random gaps and events with many ties of length."""
+    rng = np.random.default_rng(0)
+    for _ in range(cases):
+        ends = np.cumsum(rng.integers(1, 7, 2 * rng.integers(0, 40)))
+        gap_list = list(zip(ends[0::2].tolist(), ends[1::2].tolist()))
+        host = []
+        for _ in range(rng.integers(0, 40)):
+            s = int(rng.integers(0, (ends[-1] if len(ends) else 0) + 4))
+            host.append(traces.Event(
+                str(rng.choice(["bench.a", "bench.b", "x", "y", "z"])),
+                s, s + int(rng.integers(1, 9))))
+        hand: dict[str, int] = {}
+        for s, e in gap_list:
+            mid, parts = (s + e) // 2, []
+            for ours in (True, False):
+                cover = [h for h in host if h.name.startswith("bench.") == ours
+                         and h.start <= mid < h.end]
+                if cover:
+                    parts.append(min(cover, key=lambda h: h.end - h.start).name)
+            name = " / ".join(parts) or "(no host event)"
+            hand[name] = hand.get(name, 0) + (e - s)
+        got = traces.label_gaps(gap_list, host)
+        assert list(got.items()) == list(hand.items()), (got, hand)
 
 
 def main(argv=None) -> int:

@@ -6,8 +6,12 @@ segment of the cell (its worker count, sequence, batch, tau bucket and
 gossip form, at the configuration's widths), lowers and compiles the
 program for one chip of a described ``v5e:2x2`` topology and prints
 ``memory_analysis()``: what the chip's compiler refuses here costs no
-chip time. Run with ``JAX_PLATFORMS=cpu``; one compile at a time (it
-takes minutes and several GB of host memory at published widths).
+chip time. A cell whose traffic sets ``mesh_chips`` compiles
+``_scan_segment_sharded`` instead, over a mesh of that many described
+chips, with the fleet's rows split over them as ``run_dfl_fused`` splits
+them; ``memory_analysis()`` is then per chip. Run with
+``JAX_PLATFORMS=cpu``; one compile at a time (it takes minutes and
+several GB of host memory at published widths).
 
     JAX_PLATFORMS=cpu python3 bench/checks/compile_v5e.py --workload <cell>
     python3 bench/checks/compile_v5e.py --workload <cell> --chip
@@ -53,17 +57,44 @@ def main(argv=None) -> int:
     n_test = max(p["num_samples"] // 6, 256)
     n_eval = min(p["eval_rows"], n_test)
     if args.chip:
-        one = SingleDeviceSharding(jax.devices()[0])
+        devices = jax.devices()
     else:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-        one = SingleDeviceSharding(topo.devices[0])
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    one = SingleDeviceSharding(devices[0])
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     feat = (seq,) if seq else (32,)
     xdt = jnp.int32 if seq else jnp.float32
+    t = time.perf_counter()
+    if p["mesh_chips"]:
+        compiled = sharded_program(devices[:p["mesh_chips"]], adapter, w, k,
+                                   cap, b, feat, xdt, n_eval, adaptive)
+    else:
+        compiled = one_chip_program(sds, adapter, w, k, cap, b, feat, xdt,
+                                    n_eval, adaptive, sparse)
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    print(f"{cell.name}: {k} round(s) a segment, tau bucket {cap}, "
+          f"W={w}, P={adapter.param_count:,}; compiled for "
+          f"{'the chip' if args.chip else 'a described v5e'} in {time.perf_counter() - t:.1f} s; "
+          f"tpu_custom_call x{text.count('tpu_custom_call')}")
+    print(f"memory_analysis: {mem}")
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"arguments + outputs + temporaries - aliased: {peak:,} bytes "
+          f"({peak / 2**30:.2f} GiB)")
+    return 0
+
+
+def one_chip_program(sds, adapter, w, k, cap, b, feat, xdt, n_eval,
+                     adaptive, sparse):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import fused
     stacked = jax.tree.map(lambda l: sds((1, w) + l.shape, l.dtype),
                            adapter.template)
     e_max = 8 if not sparse else max(8, 1 << (2 * w - 1).bit_length())
@@ -82,24 +113,69 @@ def main(argv=None) -> int:
         sds((k, w), jnp.int32), sds((w,), jnp.bool_),
         sds((), jnp.float32), sds((2,), jnp.uint32), sds((), jnp.float32),
         sds((n_eval,) + feat, xdt), sds((n_eval,), jnp.int32))
-    t = time.perf_counter()
-    compiled = fused._scan_segment.lower(
+    return fused._scan_segment.lower(
         *args_, adapter=adapter, tau_cap=cap, measure=adaptive,
         needs_cross=False, interpret=False, kind="none", k=0, ef=True,
         sparse=sparse, lcodec=None, robust="none", rb=0.0,
         attack="").compile()
-    mem = compiled.memory_analysis()
-    text = compiled.as_text()
-    print(f"{cell.name}: {k} round(s) a segment, tau bucket {cap}, "
-          f"W={w}, P={adapter.param_count:,}; compiled for "
-          f"{'the chip' if args.chip else 'a described v5e'} in {time.perf_counter() - t:.1f} s; "
-          f"tpu_custom_call x{text.count('tpu_custom_call')}")
-    print(f"memory_analysis: {mem}")
-    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print(f"arguments + outputs + temporaries - aliased: {peak:,} bytes "
-          f"({peak / 2**30:.2f} GiB)")
-    return 0
+
+
+def sharded_program(devices, adapter, w, k, cap, b, feat, xdt, n_eval,
+                    adaptive):
+    """``_scan_segment_sharded`` over a 1-D mesh of ``devices``, with the
+    ring's edge tables built by the engine's own ``_sharded_edge_tables``
+    (the ring is the only base topology a sharded cell has)."""
+    from types import SimpleNamespace
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core import fused, topology
+    from repro.runtime import shardexec
+
+    mesh = jax.sharding.Mesh(np.array(devices), ("workers",))
+    plan = shardexec.WorkerShardPlan(mesh, w)
+    wp = plan.w_pad
+    src, dst, wt = topology.directed_edges(topology.ring_edges(w),
+                                           np.full(w, 1 / 3))
+    e_max = max(8, 1 << (len(src) - 1).bit_length())
+    pad = lambda a: np.pad(a, (0, e_max - len(a)))  # noqa: E731
+    seg = SimpleNamespace(esrc=np.stack([pad(src)] * k),
+                          edst=np.stack([pad(dst)] * k),
+                          ewt=np.stack([pad(wt)] * k))
+    offsets, esl, _, _ = fused._sharded_edge_tables(seg, plan)
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    rows = ("workers",)
+    stacked = jax.tree.map(
+        lambda l: sds((wp,) + l.shape, l.dtype, *rows), adapter.template)
+    tab = (None, None, "workers", None)
+    args_ = (
+        stacked, sds((wp, 1), jnp.float32, *rows),
+        sds((k, wp, cap, b) + feat, xdt, None, "workers"),
+        sds((k, wp, cap, b), jnp.int32, None, "workers"),
+        sds((w, 256) + feat, xdt), sds((w, 256), jnp.int32),
+        sds((w, 32) + feat, xdt), sds((w, 32), jnp.int32),
+        sds((k, wp), jnp.int32, None, "workers"), sds((k,), jnp.float32),
+        sds(esl.shape, jnp.int32, *tab), sds(esl.shape, jnp.int32, *tab),
+        sds(esl.shape, jnp.float32, *tab), sds((k,), jnp.float32),
+        sds((k, wp), jnp.float32, None, "workers"),
+        sds((k, wp), jnp.float32, None, "workers"),
+        sds((k, wp), jnp.bool_, None, "workers"),
+        sds((k, wp), jnp.float32, None, "workers"),
+        sds((k,), jnp.int32), sds((2,), jnp.uint32), sds((), jnp.float32),
+        sds((n_eval,) + feat, xdt), sds((n_eval,), jnp.int32))
+    print(f"mesh of {len(devices)} chips, {plan.rows} rows a chip; ring "
+          f"offsets {offsets}, edge tables {esl.shape}")
+    return fused._scan_segment_sharded.lower(
+        *args_, adapter=adapter, tau_cap=cap, measure=adaptive, kind="none",
+        k=0, ef=True, mesh=mesh, axes=plan.axes, offsets=offsets,
+        n_shards=plan.n_shards).compile()
 
 
 if __name__ == "__main__":

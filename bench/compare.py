@@ -33,15 +33,11 @@ there is printed but decides nothing.
 """
 from __future__ import annotations
 
-import functools
-import importlib.util
-from pathlib import Path
-
 import numpy as np
 
 import counts
+import reference
 
-BENCH = Path(__file__).resolve().parent
 MEASURE_ROUNDS = 3
 EXCLUDE_BELOW = 1e-3
 # what the reference models, by traffic key; any other value is refused
@@ -57,16 +53,6 @@ def refuse_unmodelled(traffic: dict) -> None:
     if bad:
         raise ValueError(f"the reference does not model {bad}; it models "
                          f"{MODELLED}")
-
-
-@functools.lru_cache(maxsize=None)
-def load_reference(name: str):
-    """The plain reference model ``reference/<name>.py``."""
-    spec = importlib.util.spec_from_file_location(
-        f"bench_reference_{name}", BENCH / "reference" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _rel(a, b, scale=None):
@@ -125,14 +111,15 @@ def replay_for(cell, inp, plans, dtype=None, fault: str = ""):
     refuse_unmodelled(p)
     with jax.default_matmul_precision("highest"):
         ref = dfl.replay(
-            load_reference(cell.config["reference"]), cell.config,
+            reference.load(cell.config["reference"]), cell.config,
             (inp.train.x, inp.train.y), inp.shards, inp.test_x, inp.test_y,
             seed=inp.cfg.seed, plans=plans, batch=p["batch"], lr=p["lr"],
             lr_decay=p["lr_decay"], tau_max=p["tau_max"],
             eval_subset=p["eval_rows"],
             measure_rounds=MEASURE_ROUNDS if inp.strategy.adaptive else 0,
-            dtype=jnp.float32 if dtype is None else dtype, fault=fault)
-    ref.clock = load_reference("clock").replay(
+            dtype=jnp.float32 if dtype is None else dtype, fault=fault,
+            chips=cell.chips)
+    ref.clock = reference.load("clock").replay(
         plans, seed=inp.cfg.seed, workers=inp.cfg.num_workers,
         params=counts.param_count(cell.config), tau_max=p["tau_max"])
     return ref
@@ -140,15 +127,17 @@ def replay_for(cell, inp, plans, dtype=None, fault: str = ""):
 
 def program_change(cell, inp, final_params) -> dict:
     """Norm of each (worker, leaf) change of the program's final rows from
-    the initial parameters, which the reference builds from the seed."""
+    the initial parameters, which the reference builds from the seed. A
+    fleet over several chips is reduced where its rows lie: only the
+    [W] norms of each leaf reach the host."""
     import json
 
     import jax
 
     from reference import dfl
-    f = dfl.programs(load_reference(cell.config["reference"]),
+    f = dfl.programs(reference.load(cell.config["reference"]),
                      json.dumps(cell.config, sort_keys=True),
-                     inp.cfg.num_workers, "float32", "")
+                     inp.cfg.num_workers, "float32", "", cell.chips)
     with jax.default_matmul_precision("highest"):
         init = f.init(jax.random.PRNGKey(inp.cfg.seed))
         norms = f.rows_norms(final_params, init)

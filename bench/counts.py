@@ -5,13 +5,24 @@ Counts are of the work an experiment requires, not of what the program
 happens to execute: local SGD at each worker's planned tau (not the
 masked steps up to the bucketed scan length), one forward pass per
 evaluation, Alg. 1's three gradients per worker, and the mixing.
+
+What one worker's model costs comes from the configuration's plain
+reference, ``reference/<config["reference"]>.py``, which defines
+``forward_flops_per_row(model, seq)`` and ``param_count(model)`` beside
+its ``init`` and ``loss``: a model of a new kind brings its counts with
+its reference, and nothing here changes. The modules are found by
+``reference.load``.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
 
-PEAKS = Path(__file__).resolve().parent / "peaks.json"
+import reference
+
+BENCH = Path(__file__).resolve().parent
+PEAKS = BENCH / "peaks.json"
+MODEL_COUNTS = ("forward_flops_per_row", "param_count")
 
 
 def peaks(device_kind: str) -> dict:
@@ -24,34 +35,25 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
+def model_counts(model: dict):
+    """The reference module of ``model``, which must define both of
+    ``MODEL_COUNTS``."""
+    return reference.model_module(
+        model, MODEL_COUNTS,
+        "forward_flops_per_row(model, seq) (forward FLOPs of one row of seq "
+        "tokens) and param_count(model) (P, one worker's parameters)")
+
+
 def forward_flops_per_row(model: dict, seq: int) -> float:
     """Forward FLOPs for one row of the model's batch, a sequence of
-    ``seq`` tokens (``seq - 1`` predicted positions). Multiply-adds count
-    two."""
-    if model["kind"] == "dense":
-        d, f = model["hidden_size"], model["intermediate_size"]
-        h, kv = model["num_attention_heads"], model["num_key_value_heads"]
-        hd = d // h
-        s = seq - 1
-        proj = d * h * hd * 2 + d * kv * hd * 2 + 3 * d * f
-        # causal attention: each position scores and reads its prefix
-        attn = 2 * 2 * h * hd * (s + 1) / 2
-        per_pos = (2 * proj + attn) * model["num_hidden_layers"] \
-            + 2 * d * model["vocab_size"]
-        return per_pos * s
-    raise ValueError(f"no FLOP count for model kind {model['kind']!r}")
+    ``seq`` tokens, by the configuration's reference module."""
+    return model_counts(model).forward_flops_per_row(model, seq)
 
 
 def param_count(model: dict) -> int:
-    """P, the length of one worker's row of the [W, P] matrix."""
-    if model["kind"] == "dense":
-        d, f = model["hidden_size"], model["intermediate_size"]
-        h, kv = model["num_attention_heads"], model["num_key_value_heads"]
-        hd = d // h
-        layer = d * h * hd * 2 + d * kv * hd * 2 + 3 * d * f + 2 * d
-        return (model["num_hidden_layers"] * layer
-                + 2 * d * model["vocab_size"] + d)
-    raise ValueError(f"no parameter count for model kind {model['kind']!r}")
+    """P, the length of one worker's row of the [W, P] matrix, by the
+    configuration's reference module."""
+    return model_counts(model).param_count(model)
 
 
 def round_flops(model: dict, traffic: dict, taus_sum: float,

@@ -3,7 +3,7 @@ least time its HBM bytes need at the chip's bandwidth (read [W, P] and
 the three edge tables once, write [W, P] once) over the kernel's summed
 device time. The kernel is the Pallas call (``tpu_custom_call``) whose
 operands are the int32 source and destination tables, the float32
-weights and the [W, P] rows padded to the kernel's tiles. Layer: gossip
+weights and the [W, P] rows as they lie, unpadded. Layer: gossip
 kernels."""
 
 import re

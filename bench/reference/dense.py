@@ -10,6 +10,10 @@ builds its own weights from the seed.
 ``dtype`` is the precision the reference computes and stores in:
 float32 under ``jax.default_matmul_precision("highest")`` is the
 reference; bfloat16 is the control of the correctness check.
+
+``forward_flops_per_row`` and ``param_count`` are the block's counts,
+which ``bench/counts.py`` reads for every configuration of this kind;
+``tiny`` is its cut to CPU size, which ``bench/checks/tiny.py`` reads.
 """
 from __future__ import annotations
 
@@ -17,6 +21,47 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+
+def forward_flops_per_row(model: dict, seq: int) -> float:
+    """Forward FLOPs for one row of ``seq`` tokens (``seq - 1`` predicted
+    positions). Multiply-adds count two."""
+    d, f = model["hidden_size"], model["intermediate_size"]
+    h, kv = model["num_attention_heads"], model["num_key_value_heads"]
+    hd = d // h
+    s = seq - 1
+    proj = d * h * hd * 2 + d * kv * hd * 2 + 3 * d * f
+    # causal attention: each position scores and reads its prefix
+    attn = 2 * 2 * h * hd * (s + 1) / 2
+    per_pos = (2 * proj + attn) * model["num_hidden_layers"] \
+        + 2 * d * model["vocab_size"]
+    return per_pos * s
+
+
+def param_count(model: dict) -> int:
+    """P, one worker's parameters: the leaves ``init`` builds."""
+    d, f = model["hidden_size"], model["intermediate_size"]
+    h, kv = model["num_attention_heads"], model["num_key_value_heads"]
+    hd = d // h
+    layer = d * h * hd * 2 + d * kv * hd * 2 + 3 * d * f + 2 * d
+    return (model["num_hidden_layers"] * layer
+            + 2 * d * model["vocab_size"] + d)
+
+
+TINY = {"hidden_size": 64, "intermediate_size": 128,
+        "num_attention_heads": 4, "num_key_value_heads": 2,
+        "num_hidden_layers": 2, "vocab_size": 64}
+
+
+def tiny(model: dict) -> dict:
+    """The configuration cut to a size the CPU runs in seconds, with the
+    program's model spec rebuilt to match."""
+    cfg = {**model, **TINY}
+    cfg["program_spec"] = (
+        f"dense:d={cfg['hidden_size']},heads={cfg['num_attention_heads']},"
+        f"kv={cfg['num_key_value_heads']},ff={cfg['intermediate_size']},"
+        f"layers={cfg['num_hidden_layers']},vocab={cfg['vocab_size']}")
+    return cfg
 
 
 def init(cfg: dict, key, dtype=jnp.float32) -> dict:

@@ -27,6 +27,14 @@ batches. The reference draws the same way, so it trains on the same rows.
 Nothing of the program is imported. The plan (taus and topology of each
 round) is the control plane's decision, which the reference takes as
 its input; the data set and the shards are the experiment's inputs.
+
+A fleet that spans ``chips > 1`` chips is replayed over a 1-D mesh of
+them, split along W: each chip holds its own rows, runs local SGD, the
+evaluation and the norms on them, and takes the fleet's means by
+``psum``. The mix goes leaf by leaf: a chip gathers one leaf of the
+whole fleet, multiplies it by its rows of the mixing matrix and drops
+it, so no chip ever holds more than its share of the fleet's copies
+plus one leaf of the whole fleet.
 """
 from __future__ import annotations
 
@@ -39,6 +47,7 @@ from types import SimpleNamespace
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 EVAL_STACK = 256        # rows per worker in Alg. 1's evaluation stack
 PROBE_ROWS = 32         # of which the first 32 form the noise probe
@@ -73,11 +82,12 @@ def uniform_mixing(adj: np.ndarray) -> np.ndarray:
 
 
 @functools.lru_cache(maxsize=None)
-def programs(model, cfg_json: str, w: int, dtype_name: str, fault: str):
-    """The reference's jitted steps for one model, fleet size, precision
-    and fault, built once a process: data, plans and learning rates are
-    arguments, so the compiled programs serve every seed (and the
-    persistent compile cache serves every run)."""
+def programs(model, cfg_json: str, w: int, dtype_name: str, fault: str,
+             chips: int = 1):
+    """The reference's jitted steps for one model, fleet size, precision,
+    fault and number of chips, built once a process: data, plans and
+    learning rates are arguments, so the compiled programs serve every
+    seed (and the persistent compile cache serves every run)."""
     cfg = json.loads(cfg_json)
     dtype = jnp.dtype(dtype_name)
     loss_fn = lambda p, x, y: model.loss(p, x, y, cfg)  # noqa: E731
@@ -89,8 +99,9 @@ def programs(model, cfg_json: str, w: int, dtype_name: str, fault: str):
 
     def rows_norms(a, b):
         return [jnp.sqrt(jnp.sum(jnp.square(
-            (x.astype(jnp.float32) - y.astype(jnp.float32)).reshape(w, -1)),
-            axis=1)) for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b))]
+            (x.astype(jnp.float32) - y.astype(jnp.float32)).reshape(
+                x.shape[0], -1)), axis=1))
+            for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b))]
 
     def init(key):
         return jax.tree.map(lambda l: jnp.broadcast_to(l, (w,) + l.shape),
@@ -148,6 +159,10 @@ def programs(model, cfg_json: str, w: int, dtype_name: str, fault: str):
     def pick(params, i):
         return jax.tree.map(lambda l: l[i], params)
 
+    if chips > 1:
+        return _over_chips(w, chips, loss_fn, init=init, local_sgd=local_sgd,
+                           measure_one=measure_one, first_grads=first_grads,
+                           rows_norms=rows_norms, pick=pick)
     return SimpleNamespace(**{k: jax.jit(v) for k, v in dict(
         init=init, local_sgd=local_sgd, mix=mix, evaluate=evaluate,
         measure_one=measure_one, pairwise=pairwise, first_grads=first_grads,
@@ -155,19 +170,84 @@ def programs(model, cfg_json: str, w: int, dtype_name: str, fault: str):
                                                       static_argnums=1))
 
 
+def _over_chips(w: int, chips: int, loss_fn, *, init, local_sgd,
+                measure_one, first_grads, rows_norms, pick):
+    """The steps of ``programs`` with the fleet split along W over a 1-D
+    mesh of the first ``chips`` devices. Row-wise steps run as they are,
+    on each chip's rows; the mix, the evaluation's fleet means and the
+    pairwise distances are written here with their exchanges."""
+    if w % chips:
+        raise ValueError(f"a fleet of {w} does not split over {chips} chips")
+    mesh = Mesh(np.array(jax.devices()[:chips]), ("w",))
+    rows, every = P("w"), P()
+
+    def on_rows(f, in_specs, out_specs):
+        return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs))
+
+    def gather(l):
+        return jax.lax.all_gather(l, "w", tiled=True)
+
+    def mix_rows(l, m):
+        # m: this chip's rows of the [W, W] mixing matrix
+        return jnp.tensordot(m.astype(l.dtype), gather(l), axes=1)
+
+    mix_leaf = on_rows(mix_rows, (rows, rows), rows)
+
+    def mix(params, m):
+        # one program a leaf: one leaf of the whole fleet held at a time
+        return jax.tree.map(lambda l: mix_leaf(l, m), params)
+
+    def evaluate_rows(params, tx, ty):
+        losses = jax.vmap(lambda p: loss_fn(p, tx, ty))(params)
+        mean = jax.tree.map(lambda l: jax.lax.psum(
+            jnp.sum(l.astype(jnp.float32), 0), "w") / w, params)
+        dev = sum(jnp.sum(jnp.square(l.astype(jnp.float32) - m[None])
+                          .reshape(l.shape[0], -1), axis=1)
+                  for l, m in zip(jax.tree.leaves(params),
+                                  jax.tree.leaves(mean)))
+        return (jax.lax.psum(jnp.sum(losses), "w") / w,
+                jax.lax.psum(jnp.sum(jnp.sqrt(dev)), "w") / w)
+
+    def pair_rows(l):
+        # one gathered row at a time, so no [rows, W, leaf] block is held
+        mine = l.astype(jnp.float32).reshape(l.shape[0], -1)
+        full = gather(l).astype(jnp.float32).reshape(w, -1)
+        return jax.lax.map(lambda row: jnp.sum(jnp.square(mine - row),
+                                               axis=1), full).T
+
+    pair_leaf = on_rows(pair_rows, (rows,), rows)
+
+    def pairwise(params):
+        return jnp.sqrt(sum(pair_leaf(l) for l in jax.tree.leaves(params)))
+
+    return SimpleNamespace(
+        init=jax.jit(init, out_shardings=NamedSharding(mesh, rows)),
+        local_sgd=on_rows(local_sgd, (rows, rows, rows, rows, every), rows),
+        mix=mix, mix_leaf=mix_leaf, pair_leaf=pair_leaf,
+        evaluate=on_rows(evaluate_rows, (rows, every, every),
+                         (every, every)),
+        measure_one=jax.jit(measure_one), pairwise=pairwise,
+        first_grads=on_rows(first_grads, (rows, rows, rows), rows),
+        rows_norms=on_rows(rows_norms, (rows, rows), rows),
+        pick=jax.jit(pick, static_argnums=1,
+                     out_shardings=NamedSharding(mesh, every)))
+
+
 def replay(model, cfg: dict, data, shards, test_x, test_y, *, seed: int,
            plans, batch: int, lr: float, lr_decay: float, tau_max: int,
            eval_subset: int, measure_rounds: int, dtype=jnp.float32,
-           fault: str = "") -> Replay:
+           fault: str = "", chips: int = 1) -> Replay:
     """Follow the experiment's rounds under ``plans`` ([(taus, adj)] per
     round). ``model`` has ``init(cfg, key, dtype)`` and
     ``loss(params, x, y, cfg)``. ``fault`` plants a fault in the
     reference for the check's readings ("half_batch": the SGD step sees
-    the first half of each batch)."""
+    the first half of each batch). ``chips`` > 1 splits the fleet over
+    that many chips."""
     w = len(shards)
     x_all, y_all = data
     f = programs(model, json.dumps(cfg, sort_keys=True), w,
-                 jnp.dtype(dtype).name, fault)
+                 jnp.dtype(dtype).name, fault, chips)
 
     rng = np.random.default_rng(seed)
     ex = np.stack([x_all[sh[rng.integers(0, len(sh), EVAL_STACK)]]

@@ -252,12 +252,13 @@ def memory_peak_bytes(n: int) -> int:
     programs' temporaries, which the TPU runtime keeps apart from the
     buffers (``peak_bytes_reserved``)."""
     import jax
+    return max(held_at_peak(d) for d in jax.devices()[:n])
 
-    def held(d):
-        s = d.memory_stats() or {}
-        return int(s.get("peak_bytes_in_use", 0)) + \
-            int(s.get("peak_bytes_reserved", 0))
-    return max(held(d) for d in jax.devices()[:n])
+
+def held_at_peak(device) -> int:
+    s = device.memory_stats() or {}
+    return int(s.get("peak_bytes_in_use", 0)) + \
+        int(s.get("peak_bytes_reserved", 0))
 
 
 # ---------------------------------------------------------------------------
@@ -311,7 +312,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             hist.final_params = None
     window_s = time.perf_counter() - t0
     if trace:
+        t = time.perf_counter()
         jax.profiler.stop_trace()
+        trace_s = {"stop": time.perf_counter() - t}
     counter.window = False
     rounds_done = sum(len(r) for r in records)
     peak = memory_peak_bytes(cell.chips)
@@ -323,6 +326,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     result = {"attempted": rounds_done, "device": dict(device)}
     result["device"]["memory_peak_bytes"] = peak
     if trace:
+        t = time.perf_counter()
         run = traces.TraceRun.load(trace_dir.name, cell=cell, device=device,
                                    records=records, plans=rec.plans,
                                    eval_rows=min(inp.params["eval_rows"],
@@ -330,14 +334,22 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                                    window_s=window_s,
                                    compiles=counter.in_window)
         trace_dir.cleanup()
+        trace_s["load"] = time.perf_counter() - t
         metrics = {}
         for m in cell.per_layer:
+            t = time.perf_counter()
             value = load_metric(m["name"]).read(run)
+            trace_s[m["name"]] = time.perf_counter() - t
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         result["device"]["busy_s"] = run.busy_s
         result["device"]["window_s"] = run.window_s
+        t = time.perf_counter()
         result["breakdown"] = run.breakdown()
+        trace_s["breakdown"] = time.perf_counter() - t
+        log(f"trace reduction seconds {trace_s}; device operations in the "
+            f"window by chip {[len(v) for v in run.ops.values()]}, host "
+            f"events {len(run.host)}")
     else:
         values = {"round_ms": window_s * 1000.0 / rounds_done,
                   "peak_hbm_gib": peak / GIB, "setup_s": setup_s}
@@ -349,7 +361,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     readings, ref_s = compare.check(cell, inp, hist, rec, records)
     hist.final_params = None
     log(f"reference and comparison {time.perf_counter() - t:.3f} s; "
-        f"reference by phase {ref_s}")
+        f"reference by phase {ref_s}; allocator peak by chip after it "
+        f"{[held_at_peak(d) for d in jax.devices()[:cell.chips]]} bytes "
+        f"(the window's {peak}: a chip that reads more peaked in the "
+        f"reference)")
     for c in readings:
         if c["limit"] is None:
             log(f"reading {c['name']}: {c['value']!r} (no limit)")

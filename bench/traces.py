@@ -64,7 +64,10 @@ def line_events(pd, plane_pred, line_pred) -> dict[str, list[Event]]:
 
 
 def clip(events: list[Event], lo: int, hi: int) -> list[Event]:
-    return [Event(e.name, max(e.start, lo), min(e.end, hi))
+    """The events that overlap [lo, hi], cut to it (one inside it is kept
+    as it is)."""
+    return [e if lo <= e.start and e.end <= hi
+            else Event(e.name, max(e.start, lo), min(e.end, hi))
             for e in events if e.end > lo and e.start < hi]
 
 
@@ -114,21 +117,43 @@ def by_name(events: list[Event]) -> dict[str, int]:
 def label_gaps(gap_list, host: list[Event]) -> dict[str, int]:
     """Idle ns by what the host was doing at each gap's middle: the
     innermost ``bench.*`` annotation covering it, and the shortest other
-    host event covering it."""
+    host event covering it (of equally short ones, the first listed).
+
+    Events are taken from the shortest up, and each labels the middles it
+    covers that no shorter event has labelled; a union-find over the
+    sorted middles skips those already taken, so the cost grows with the
+    number of gaps and events, not with their product."""
+    import bisect
+
     import numpy as np
-    groups = []
+    mids = [(s + e) // 2 for s, e in gap_list]
+    order = sorted(range(len(mids)), key=mids.__getitem__)
+    sorted_mids = [mids[i] for i in order]
+    labels = [[] for _ in mids]
     for ours in (True, False):
         evs = [h for h in host if h.name.startswith("bench.") == ours]
-        groups.append((evs, np.array([h.start for h in evs], np.int64),
-                       np.array([h.end for h in evs], np.int64)))
+        durs = np.array([h.end - h.start for h in evs], np.int64)
+        nxt = list(range(len(mids) + 1))    # next middle not yet labelled
+
+        def find(i):
+            root = i
+            while nxt[root] != root:
+                root = nxt[root]
+            while nxt[i] != root:
+                nxt[i], i = root, nxt[i]
+            return root
+
+        for k in np.argsort(durs, kind="stable").tolist():
+            h = evs[k]
+            # middles m with h.start <= m < h.end
+            i = find(bisect.bisect_left(sorted_mids, h.start))
+            stop = bisect.bisect_left(sorted_mids, h.end)
+            while i < stop:
+                labels[order[i]].append(h.name)
+                nxt[i] = i + 1
+                i = find(i + 1)
     out: dict[str, int] = {}
-    for s, e in gap_list:
-        mid = (s + e) // 2
-        parts = []
-        for evs, starts, ends in groups:
-            hit = np.nonzero((starts <= mid) & (ends > mid))[0]
-            if hit.size:
-                parts.append(evs[hit[np.argmin(ends[hit] - starts[hit])]].name)
+    for (s, e), parts in zip(gap_list, labels):
         name = " / ".join(parts) or "(no host event)"
         out[name] = out.get(name, 0) + (e - s)
     return out
@@ -201,9 +226,11 @@ class TraceRun:
         """The operations that took most device time (loops, which hold
         the operations of their bodies, left out) and the longest idle
         stretches by what the host was doing."""
-        ops = by_name([Event(short_name(e.name), e.start, e.end)
-                       for v in self.ops.values() for e in v
-                       if not e.name.startswith("%while")])
+        ops: dict[str, int] = {}
+        for name, t in by_name([e for v in self.ops.values() for e in v
+                                if not e.name.startswith("%while")]).items():
+            short = short_name(name)
+            ops[short] = ops.get(short, 0) + t
         top = sorted(ops.items(), key=lambda kv: -kv[1])[:10]
         first = next(iter(self.ops.values()))
         idle = label_gaps(gaps(first, self.lo, self.hi), self.host)
